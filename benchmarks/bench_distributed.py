@@ -22,11 +22,10 @@ _SCRIPT = textwrap.dedent("""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.completion import als_sweep
-    from repro.core.distributed import AxisCtx, DistLayout, LOCAL
+    from repro.core.distributed import DistLayout, LOCAL, make_mesh
     from repro.data.pipeline import CompletionDataset
     from repro.data import synthetic
 
@@ -38,7 +37,7 @@ _SCRIPT = textwrap.dedent("""
 
     key = jax.random.PRNGKey(0)
     raw = synthetic.function_tensor(key, dims, nnz)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     layout = DistLayout(mesh, ("data",), "model")
     ctx = layout.ctx
     ds = CompletionDataset(raw, key, mesh=mesh, data_axes=("data",))
@@ -62,17 +61,27 @@ _SCRIPT = textwrap.dedent("""
 
     st_spec = layout.sparse_specs(st)
     f_spec = layout.factor_spec()
-    mesh_fn = jax.jit(shard_map(
+    mesh_fn = jax.jit(jax.shard_map(
         lambda s, o, fs: tuple(als_sweep(s, o, list(fs), 1e-6,
                                          cg_iters=10, ctx=ctx)),
         mesh=mesh, in_specs=(st_spec, st_spec, (f_spec,) * 3),
-        out_specs=((f_spec,) * 3), check_rep=False))
+        out_specs=((f_spec,) * 3), check_vma=False))
     print(f"dist_als_sweep_mesh4x2 {timeit(mesh_fn, st, omega, factors):.1f}")
     print("BENCH-DIST-DONE")
 """)
 
 
 def run(quick: bool = False):
+    import jax
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        # this process has initialized JAX and holds the accelerator, and
+        # the child would time forced CPU devices under this host's name
+        raise SystemExit(
+            f"bench_distributed times 8 forced CPU host devices in a child "
+            f"process; this host's JAX platform is {platform!r}, so the "
+            f"numbers would not be device numbers. Run the mesh path on the "
+            f"chip with `python chip_smoke.py --four-chips` instead.")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"

@@ -180,11 +180,11 @@ class _Interp:
     def run(self, jaxpr, in_states: Sequence[AxisStates],
             const_states: Optional[Sequence[AxisStates]] = None
             ) -> List[AxisStates]:
-        import jax
+        import jax.extend.core
         env: Dict = {}
 
         def read(atom) -> AxisStates:
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, jax.extend.core.Literal):
                 return self._rep()
             return env.get(atom, self._rep())
 

@@ -95,6 +95,12 @@ def read_manifest(directory: str, step: int) -> dict:
         return json.load(f)
 
 
+class StructureMismatch(ValueError):
+    """A checkpoint whose state structure differs from the restore target
+    (missing leaves, another shape or dtype): not a corrupt file, so no
+    older step of the same directory can be the right one either."""
+
+
 def _validate_leaf(path: str, key: str, arr: np.ndarray, entry: dict,
                    like_leaf) -> None:
     """Fail fast, naming the offending leaf: (a) the on-disk array must match
@@ -110,12 +116,12 @@ def _validate_leaf(path: str, key: str, arr: np.ndarray, entry: dict,
             f"{m_shape}/{entry['dtype']} — corrupted or partially written")
     like_shape = tuple(np.shape(like_leaf))
     if like_shape != m_shape:
-        raise ValueError(
+        raise StructureMismatch(
             f"checkpoint {path}: leaf {key!r} has shape {m_shape} but the "
             f"restore target expects {like_shape} — checkpoint/structure "
             f"drift (e.g. rank changed between fit and serve)")
     if hasattr(like_leaf, "dtype") and np.dtype(like_leaf.dtype) != arr.dtype:
-        raise ValueError(
+        raise StructureMismatch(
             f"checkpoint {path}: leaf {key!r} has dtype {arr.dtype} but the "
             f"restore target expects {np.dtype(like_leaf.dtype)}")
 
@@ -135,7 +141,7 @@ def restore(directory: str, step: int, like,
     recorded = manifest.get("leaves", {})
     missing = sorted(set(leaves) - set(recorded))
     if missing:
-        raise ValueError(
+        raise StructureMismatch(
             f"checkpoint {path}: leaves {missing} absent from the manifest "
             f"(it records {sorted(recorded)}) — structure drift")
     out = {}

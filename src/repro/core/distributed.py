@@ -31,8 +31,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.sparse_tensor import SparseTensor
 from repro.core.utils import axis_size
@@ -67,6 +66,15 @@ class AxisCtx:
 LOCAL = AxisCtx()
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes. The default ``Explicit`` axes
+    make eager gathers on sharded arrays raise ``ShardingTypeError``; this
+    code places its own collectives inside ``shard_map`` and leaves the rest
+    to the partitioner."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 @dataclasses.dataclass
 class DistLayout:
     """Mesh + specs for the completion workload."""
@@ -95,8 +103,8 @@ class DistLayout:
         return P(None, self.model_axis)  # rows replicated, columns H-sliced
 
     def shard(self, fn: Callable, in_specs, out_specs) -> Callable:
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -66,7 +66,7 @@ def _cg_matvec_kernel(other_slots, mode, block_rows, block_m, num_tiles, g,
 def cg_matvec_pallas(buckets: RowBlockBuckets,
                      factors: Sequence[Optional[jax.Array]],
                      x: jax.Array, tile: Optional[KernelTile] = None,
-                     interpret: bool = True) -> jax.Array:
+                     *, interpret: bool) -> jax.Array:
     """Fused Gram matvec over Ω-pattern buckets (bucketed over ``mode``).
 
     ``buckets.values`` must hold the Ω indicator (1.0 at observed entries,

@@ -84,7 +84,7 @@ def mttkrp_pallas(buckets: RowBlockBuckets,
                   factors: Sequence[Optional[jax.Array]],
                   block_r: Optional[int] = None,
                   tile: Optional[KernelTile] = None,
-                  interpret: bool = True) -> jax.Array:
+                  *, interpret: bool) -> jax.Array:
     """Bucketed MTTKRP. Returns (padded rows, R) in ``tile.accum_dtype``;
     callers slice to ``shape[mode]`` rows and cast. R must be a multiple of
     the resolved ``block_r`` (ops.py pads); capacity and bucket-count

@@ -1,12 +1,23 @@
 """jit'd wrappers dispatching between the Pallas kernels and the pure-jnp
 reference paths, with shape padding to block multiples.
 
-Dispatch policy: Pallas (interpret on CPU, compiled on TPU) when
-``use_pallas`` or the per-call default says so; pure jnp otherwise. The
-device probe is resolved lazily PER CALL (never at import): late device
-initialization (``--force-host-devices``) and tests that flip
-``REPRO_USE_PALLAS`` both see the current state, not an import-time
-snapshot.
+Dispatch policy, in one place (:func:`route`): each kernel family goes
+either to its Pallas kernel or to the XLA (pure jnp) path.
+
+* On a TPU a family goes to Pallas only if its kernel compiles for the
+  chip. ``TPU_REFUSED`` names the families the TPU compiler refuses and
+  why; ``tests/test_tpu_compile.py`` compiles each kernel for a described
+  v5e, so a kernel that starts to compile fails its strict xfail there and
+  its entry here has to go. Asking for a refused kernel (``use_pallas=True``
+  or ``REPRO_USE_PALLAS=1``) raises with the compiler's reason: it never
+  falls back quietly and never runs in interpret mode.
+* Elsewhere (CPU) the XLA path is the default; ``use_pallas=True`` or
+  ``REPRO_USE_PALLAS=1`` runs the kernels in interpret mode, which is how
+  the kernels are tested here.
+
+The platform is probed once, lazily (never at import): late device
+initialization (``--force-host-devices``) must come first. The
+environment variable is read per call, so tests can flip it.
 
 All wrappers are shape-polymorphic over padding: inputs are padded to block
 multiples and outputs sliced back. Block sizes come from a
@@ -21,6 +32,7 @@ both routes return identical dtypes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Optional, Sequence
 
@@ -37,16 +49,55 @@ from repro.kernels.mttkrp import mttkrp_pallas
 from repro.kernels.tttp import tttp_pallas
 
 
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
+# Mosaic lowers only a same-shape take_along_axis gather; every kernel here
+# gathers block_m factor rows from an (I_d, block_r) ref with jnp.take.
+_MOSAIC_ROW_GATHER = (
+    "the TPU compiler (Mosaic) refuses the in-kernel row gather - jnp.take "
+    "of block_m rows from an (I_d, block_r) factor ref fails with 'Shape "
+    "mismatch in input, indices and output' at every geometry")
+
+_BUCKET_BLOCK = (
+    "; with buckets_per_step < 8 the (buckets_per_step, capacity) block is "
+    "refused first, as not (8, 128)-tiled")
+
+TPU_REFUSED = {
+    "tttp": _MOSAIC_ROW_GATHER,
+    "mttkrp": _MOSAIC_ROW_GATHER + _BUCKET_BLOCK,
+    "cg_matvec": _MOSAIC_ROW_GATHER + _BUCKET_BLOCK,
+}
 
 
-def _default_use_pallas() -> bool:
-    return os.environ.get("REPRO_USE_PALLAS", "0") == "1" or _on_tpu()
+@functools.cache
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+def route(family: str, use_pallas: Optional[bool] = None) -> str:
+    """``"pallas"`` or ``"xla"`` for one kernel family (module docstring).
+    ``use_pallas=None`` defers to ``REPRO_USE_PALLAS`` (unset: the platform
+    default)."""
+    if use_pallas is None and "REPRO_USE_PALLAS" in os.environ:
+        use_pallas = os.environ["REPRO_USE_PALLAS"] == "1"
+    if _platform() != "tpu":
+        return "pallas" if use_pallas else "xla"
+    refused = TPU_REFUSED.get(family)
+    if refused is None:
+        return "xla" if use_pallas is False else "pallas"
+    if use_pallas:
+        raise RuntimeError(f"the Pallas {family} kernel cannot run on this "
+                           f"TPU: {refused}; unset REPRO_USE_PALLAS to take "
+                           f"the XLA path")
+    return "xla"
+
+
+def _use_pallas(family: str, use_pallas: Optional[bool]) -> bool:
+    r = route(family, use_pallas)
+    obs.counter_add(f"kernel/{family}/{r}")
+    return r == "pallas"
 
 
 def _interpret() -> bool:
-    return not _on_tpu()
+    return _platform() != "tpu"
 
 
 def _resolve_tile(family: str, tile: Optional[ktile.KernelTile],
@@ -83,7 +134,7 @@ def tttp_values(st: SparseTensor, factors: Sequence[Optional[jax.Array]],
                 tile: Optional[ktile.KernelTile] = None) -> jax.Array:
     """TTTP output values for a padded-COO SparseTensor. Vector factors are
     promoted to single-column matrices (paper's vector-list form)."""
-    use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
+    use_pallas = _use_pallas("tttp", use_pallas)
     factors = [None if f is None else (f[:, None] if f.ndim == 1 else f)
                for f in factors]
     t = _resolve_tile("tttp", tile, block_m=block_m, block_r=block_r)
@@ -112,7 +163,7 @@ def mttkrp_bucketed(buckets, factors: Sequence[Optional[jax.Array]],
                     block_r: Optional[int] = None,
                     tile: Optional[ktile.KernelTile] = None) -> jax.Array:
     """All-at-once MTTKRP over ingest-time buckets; returns (num_rows, R)."""
-    use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
+    use_pallas = _use_pallas("mttkrp", use_pallas)
     num_rows = num_rows or buckets.shape[buckets.mode]
     t = _resolve_tile("mttkrp", tile, block_r=block_r)
     with obs.span("kernel/mttkrp_bucketed", mode=buckets.mode,
@@ -133,7 +184,7 @@ def cg_matvec_bucketed(buckets, factors: Sequence[Optional[jax.Array]],
                        use_pallas: Optional[bool] = None,
                        tile: Optional[ktile.KernelTile] = None) -> jax.Array:
     """Fused implicit-CG Gram matvec; buckets hold the Ω indicator values."""
-    use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
+    use_pallas = _use_pallas("cg_matvec", use_pallas)
     num_rows = num_rows or buckets.shape[buckets.mode]
     t = _resolve_tile("cg_matvec", tile)
     with obs.span("kernel/cg_matvec_bucketed", mode=buckets.mode,

@@ -20,8 +20,9 @@ Blocking / memory notes (TPU target, validated in interpret mode on CPU):
 * for factor matrices too large for VMEM the production path keeps factors in
   HBM (``memory_space=ANY``) and DMA-streams gathered rows; on this CPU
   container we validate the VMEM-resident variant only (DESIGN.md §3).
-* the row gather uses ``jnp.take`` along axis 0, which lowers to TPU dynamic
-  row-gather; padded entries carry value 0 and index 0, so they contribute 0.
+* the row gather uses ``jnp.take`` along axis 0, which the TPU compiler
+  refuses (``kernels.ops.TPU_REFUSED``), so on a TPU this kernel does not
+  run yet; padded entries carry value 0 and index 0, so they contribute 0.
 """
 from __future__ import annotations
 
@@ -63,7 +64,7 @@ def tttp_pallas(values: jax.Array, indices: jax.Array,
                 block_m: Optional[int] = None,
                 block_r: Optional[int] = None,
                 tile: Optional[KernelTile] = None,
-                interpret: bool = True) -> jax.Array:
+                *, interpret: bool) -> jax.Array:
     """TTTP on padded COO arrays. ``values (m,)``, ``indices (m, nd)``;
     ``factors[d]`` is ``(shape[d], R)`` or None. m must be a multiple of
     ``block_m · buckets_per_step`` and R of ``block_r`` (ops.py pads).

@@ -94,8 +94,23 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_parser().parse_args()
+def train_rmse(st, factors) -> float:
+    """RMSE of the CP model over the valid entries of ``st``."""
+    import jax.numpy as jnp
+    from repro.core.tttp import multilinear_values
+
+    model = multilinear_values(st, factors)
+    d = (st.values - model) * st.mask
+    n = jnp.maximum(jnp.sum(st.mask), 1)
+    return float(jnp.sqrt(jnp.sum(jnp.square(d)) / n))
+
+
+def main(argv=None) -> dict:
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``). Returns the run:
+    ``history`` — one ``(sweep, seconds, rmse)`` per sweep run in this
+    process —, ``compile_seconds``, the final ``factors`` and the training
+    ``tensor``."""
+    args = build_parser().parse_args(argv)
     if args.force_host_devices:
         flags = os.environ.get("XLA_FLAGS", "")
         os.environ["XLA_FLAGS"] = (
@@ -103,12 +118,13 @@ def main():
             f"{args.force_host_devices}").strip()
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     # deferred: repro.kernels probes jax.devices() at import, which pins the
     # backend — XLA_FLAGS must be in the environment first
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import losses as LOSS
@@ -118,9 +134,7 @@ def main():
     from repro.core.completion.gcp import AdamState
     from repro.core.completion.ccd import residual_values
     from repro.core.completion.gauss_newton import GGNState
-    from repro.core.distributed import AxisCtx, DistLayout, LOCAL
-    from repro.core.sparse_tensor import SparseTensor
-    from repro.core.tttp import multilinear_values
+    from repro.core.distributed import DistLayout, LOCAL, make_mesh
     from repro.data import synthetic
     from repro.data.pipeline import CompletionDataset
     from repro.runtime.fault_tolerance import RestartableLoop
@@ -135,12 +149,6 @@ def main():
         from repro.planner import PlannerConfig, set_default_config
         set_default_config(PlannerConfig(block_rows=args.block_rows))
 
-    def rmse(st: SparseTensor, factors) -> float:
-        model = multilinear_values(st, factors)
-        d = (st.values - model) * st.mask
-        n = jnp.maximum(jnp.sum(st.mask), 1)
-        return float(jnp.sqrt(jnp.sum(jnp.square(d)) / n))
-
     # ---- mesh / ctx ------------------------------------------------------
     mesh, ctx = None, LOCAL
     data_axes = ("data",)
@@ -154,7 +162,7 @@ def main():
             raise SystemExit(
                 f"--mesh {args.mesh} needs {need} devices but only {have} "
                 f"are visible; on CPU pass --force-host-devices {need}")
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = make_mesh(mesh_shape, axes)
         data_axes = tuple(a for a in args.data_axes.split(",") if a)
         model_axes = [a for a in axes if a not in data_axes]
         model_axis = model_axes[0] if model_axes else None
@@ -219,8 +227,8 @@ def main():
         """jit, under shard_map when a mesh is configured."""
         if mesh is None:
             return jax.jit(fn)
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     if mesh is not None:
         st_spec = layout.sparse_specs(st)
@@ -234,7 +242,7 @@ def main():
                       cg_iters=args.cg_iters, ctx=ctx)),
                   (st_spec, st_spec, fs_spec), fs_spec)
         state0 = tuple(factors)
-        step = lambda i, fs: tuple(fn(st, omega, tuple(fs)))
+        call_args = lambda i, fs: (st, omega, tuple(fs))
     elif args.algorithm in ("ccd", "ccd_tttp"):
         sweep = ccd_sweep if args.algorithm == "ccd" else ccd_sweep_tttp
         fn = wrap(lambda s, fs, rho: (lambda f, r_: (tuple(f), r_))(
@@ -244,15 +252,14 @@ def main():
                   (fs_spec, None if mesh is None else st_spec.values))
         rho0 = residual_values(st, factors)
         state0 = (tuple(factors), rho0)
-        step = lambda i, stt: fn(st, stt[0], stt[1])
+        call_args = lambda i, stt: (st, tuple(stt[0]), stt[1])
     elif args.algorithm == "sgd":
         fn = wrap(lambda k, s, fs: tuple(sgd_sweep(
                       k, s, list(fs), args.lam, args.lr, sample, ctx=ctx)),
                   (P() if mesh is not None else None, st_spec, fs_spec),
                   fs_spec)
         state0 = tuple(factors)
-        step = lambda i, fs: tuple(fn(jax.random.fold_in(key, i), st,
-                                      tuple(fs)))
+        call_args = lambda i, fs: (jax.random.fold_in(key, i), st, tuple(fs))
     elif args.algorithm == "ggn":
         if args.matvec_path == "fused":
             print("note: under jit/shard_map the 'fused' matvec path falls "
@@ -270,7 +277,7 @@ def main():
                    else GGNState(fs_spec, P())),
                   None if mesh is None else GGNState(fs_spec, P()))
         state0 = ggn_init(factors, damping=args.damping)
-        step = lambda i, stt: fn(st, stt)
+        call_args = lambda i, stt: (st, stt)
     else:  # gcp
         ad0 = gcp_adam_init(factors)
         ad_spec = None if mesh is None else AdamState(
@@ -280,7 +287,14 @@ def main():
                                 ctx=ctx)),
                   (st_spec, fs_spec, ad_spec), (fs_spec, ad_spec))
         state0 = (tuple(factors), ad0)
-        step = lambda i, stt: fn(st, tuple(stt[0]), stt[1])
+        call_args = lambda i, stt: (st, tuple(stt[0]), stt[1])
+
+    # compile ahead of the first sweep so its time is reported on its own;
+    # the jit call below reuses the executable
+    t0 = time.perf_counter()
+    fn.lower(*call_args(0, state0)).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compile {compile_s:.2f} s")
 
     def get_factors(state):
         if isinstance(state, GGNState):
@@ -293,10 +307,10 @@ def main():
 
     def loop_step(i, state):
         t0 = time.perf_counter()
-        state = step(i, state)
-        jax.block_until_ready(jax.tree.leaves(state)[0])
+        state = fn(*call_args(i, state))
+        jax.block_until_ready(state)
         dt = time.perf_counter() - t0
-        e = rmse(st, get_factors(state))
+        e = train_rmse(st, get_factors(state))
         hist.append((i, dt, e))
         print(f"sweep {i:3d}  {dt*1e3:8.1f} ms  rmse={e:.6f}")
         return state
@@ -307,7 +321,7 @@ def main():
         print(f"final rmse={hist[-1][2]:.6f} "
               f"(mean sweep {sum(h[1] for h in hist)/len(hist)*1e3:.1f} ms)")
     else:  # checkpoint resume found every sweep already done
-        print(f"final rmse={rmse(st, get_factors(final)):.6f} "
+        print(f"final rmse={train_rmse(st, get_factors(final)):.6f} "
               f"(all {args.sweeps} sweeps restored from {args.ckpt_dir})")
     if args.dump_factors:
         fs = get_factors(final)
@@ -327,6 +341,8 @@ def main():
                                 "dataset": args.dataset,
                                 "nnz": int(st.nnz), "sweeps": args.sweeps})
         print(f"wrote factors to {args.dump_factors}")
+    return {"history": hist, "compile_seconds": compile_s,
+            "factors": get_factors(final), "tensor": st}
 
 
 if __name__ == "__main__":

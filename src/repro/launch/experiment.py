@@ -378,6 +378,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.list or args.spec is None:
         for name, s in sorted(SPECS.items()):
             print(f"{name:16s} {s.dataset:9s} shape={s.shape} nnz={s.nnz} "

@@ -260,6 +260,8 @@ def main():
     ap.add_argument("--repeats", type=int, default=5,
                     help="eager planned runs per kernel for --perf")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.perf:
         perf = collect_perf(args.spec, repeats=args.repeats)
         text = render_perf_md(perf, args.bench_dir)

@@ -125,8 +125,13 @@ def _verify_foldin(model, histories, mode, lam, rows) -> float:
     return err
 
 
-def main() -> None:
-    args = build_parser().parse_args()
+def main(argv=None) -> dict:
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``) and return the
+    report; exits with status 1 when ``--verify`` finds a mismatch."""
+    args = build_parser().parse_args(argv)
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import jax
     import numpy as np
@@ -223,6 +228,7 @@ def main() -> None:
         sys.exit(1)
     if args.verify:
         print("verify OK")
+    return report
 
 
 if __name__ == "__main__":

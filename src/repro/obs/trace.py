@@ -74,11 +74,8 @@ def trace_clean() -> bool:
     """True when NOT under a jax trace (jit/grad/vmap/shard_map tracing).
     Deferred jax import: obs must stay importable before jax initializes
     (the launch drivers set XLA flags first)."""
-    try:
-        import jax
-        return jax.core.trace_state_clean()
-    except Exception:
-        return True
+    import jax
+    return jax.core.trace_ctx.is_top_level()
 
 
 _trace_clean = trace_clean

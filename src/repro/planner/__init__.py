@@ -79,10 +79,10 @@ def planned_einsum(expr: str, *operands, path: Optional[str] = None,
     inside dispatch, communication terms in the ranking — DESIGN.md §9)."""
     if plan is None:
         if not any(isinstance(op, SparseTensor) for op in operands):
-            # pure-dense: nothing to plan — delegate untouched, preserving
-            # jnp.einsum's acceptance of lists/scalars
+            # pure-dense: nothing to plan — delegate to jnp.einsum, which
+            # takes arrays only (lists/scalars are converted here)
             import jax.numpy as jnp
-            return jnp.einsum(expr, *operands)
+            return jnp.einsum(expr, *map(jnp.asarray, operands))
         plan = plan_contraction(expr, operands, path=path, autotune=autotune,
                                 ctx=ctx, rowsharded=rowsharded, config=config)
     return plan.execute(operands)

@@ -28,7 +28,7 @@ jit-safe; the ``bucketed``/``fused`` paths consume the ingest-time cached
 ``RowBlockBuckets`` view on the SparseTensor (``SparseTensor.row_buckets``)
 — values are re-gathered through the cached pattern per call — and fall
 back to ``all_at_once``/``tttp_mttkrp`` when no pattern is available under
-tracing.
+tracing, bumping a ``dispatch/fallback/*`` obs counter at trace time.
 """
 from __future__ import annotations
 
@@ -39,6 +39,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import tttp as core_tttp
 from repro.core.distributed import AxisCtx, LOCAL
 from repro.core.sparse_tensor import SparseTensor
@@ -197,6 +198,7 @@ def _exec_mttkrp(ir: pir.ContractionIR, st: SparseTensor, dense_ops,
             res = kops.mttkrp_bucketed(buckets, factors,
                                        num_rows=st.shape[mode])
         else:                                    # tracing, no cached pattern
+            obs.counter_add("dispatch/fallback/mttkrp_bucketed")
             res = sops.mttkrp(st, factors, mode)
     elif path == "all_at_once":
         res = sops.mttkrp(st, factors, mode)
@@ -249,6 +251,8 @@ def _exec_cg_matvec(ir: pir.ContractionIR, st: SparseTensor, dense_ops,
             res = kops.cg_matvec_bucketed(buckets, r_fac, x,
                                           num_rows=st.shape[mode])
             return ctx.psum_data(_reorder(res, canon, ir.out))
+    if path == "fused":
+        obs.counter_add("dispatch/fallback/cg_matvec_fused")
     if path in ("fused", "tttp_mttkrp"):
         partial = ctx.psum_model(core_tttp.multilinear_values(st, s_fac))
         z = st.with_values(st.values * partial)
@@ -287,7 +291,6 @@ def execute(ir: pir.ContractionIR, path: str, operands: Sequence,
     flop/traffic/comm prediction for this (IR, path) next to the fenced
     wall time — the persistent accounting that validates the cost model
     (DESIGN.md §11). Traced executions (inside jit) skip all of it."""
-    from repro import obs
     if not (obs.enabled() and obs.trace_clean()):
         return _execute(ir, path, operands, ctx, config)
     kind = str(ir.kind)

@@ -21,7 +21,8 @@ from typing import Any, Callable, Optional
 import jax
 
 from repro import obs
-from repro.checkpoint.checkpointer import Checkpointer, restore, _list_steps
+from repro.checkpoint.checkpointer import (Checkpointer, StructureMismatch,
+                                           restore, _list_steps)
 
 log = logging.getLogger(__name__)
 
@@ -73,17 +74,24 @@ class RestartableLoop:
         self.last_metadata: dict = {}
 
     def _resume(self, init_state):
-        """Newest-first restore with corrupted-checkpoint fallback."""
+        """Newest-first restore. A corrupt or partially written step falls
+        back to the next older one; a step holding another state structure
+        (``StructureMismatch``, e.g. another solver's checkpoint in the same
+        directory) raises."""
         steps = sorted(_list_steps(self.ckpt.directory), reverse=True)
         for s in steps:
             try:
                 state, manifest = restore(self.ckpt.directory, s, init_state)
-                log.info("resumed from step %d", s)
-                self.last_metadata = manifest.get("metadata", {}) or {}
-                return s + 1, state
-            except Exception as e:  # corrupt/partial: fall back
+            except StructureMismatch:
+                raise
+            except (OSError, ValueError, EOFError) as e:  # corrupt/partial
                 log.warning("checkpoint step %d unreadable (%s); falling back",
                             s, e)
+                continue
+            print(f"resumed from checkpoint step {s} in "
+                  f"{self.ckpt.directory}")
+            self.last_metadata = manifest.get("metadata", {}) or {}
+            return s + 1, state
         return 0, init_state
 
     def run(self, init_state, num_steps: int, fail_at: Optional[int] = None):

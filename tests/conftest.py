@@ -1,8 +1,8 @@
 import os
 import sys
 
-# smoke tests and benches must see ONE device (the dry-run sets its own flag
-# in a separate process); keep determinism and quiet logs.
+# tests run on the CPU with one device per process; a test that needs
+# several devices starts a subprocess with forced host devices (XLA_FLAGS).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

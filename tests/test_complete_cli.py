@@ -9,6 +9,7 @@ single-device view per the harness contract."""
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -60,3 +61,42 @@ def test_mesh_run_matches_local(tmp_path, algo, mesh, rank):
     for k in local.files:
         np.testing.assert_allclose(dist[k], local[k], rtol=1e-4, atol=1e-4,
                                     err_msg=f"{algo} factor {k}")
+
+
+def test_main_in_process_runs_every_sweep(tmp_path):
+    """``main(argv)`` is callable in-process (chip_smoke.py drives it that
+    way) and reports every sweep it ran."""
+    from repro.launch import complete
+
+    res = complete.main(["--dataset", "netflix", "--dims", "30,20,10",
+                         "--nnz", "800", "--rank", "4", "--sweeps", "3",
+                         "--lam", "1e-2", "--ckpt-dir", str(tmp_path)])
+    assert [h[0] for h in res["history"]] == [0, 1, 2]
+    assert res["history"][-1][2] < res["history"][0][2]
+    assert res["compile_seconds"] > 0
+    assert complete.train_rmse(res["tensor"], res["factors"]) == \
+        pytest.approx(res["history"][-1][2], rel=1e-6)
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env):
+    import jax
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(compile_cache.CHECKOUT_CACHE_DIR)
+    else:
+        want = str(tmp_path / env)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert compile_cache.use_compile_cache() == want
+        if env is None:
+            assert jax.config.jax_compilation_cache_dir == want
+            assert compile_cache.CHECKOUT_CACHE_DIR.parent == \
+                Path(__file__).resolve().parents[1]
+        else:
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -16,7 +16,8 @@ _SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.core.distributed import make_mesh
     from jax.sharding import PartitionSpec as P
 
     from repro.core.sparse_tensor import SparseTensor
@@ -27,7 +28,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.data.synthetic import shuffle_and_pad
     from repro.optim.compression import compressed_psum, ef_state_init
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = AxisCtx(data="data", model="model")
 
     key = jax.random.PRNGKey(0)
@@ -48,7 +49,7 @@ _SCRIPT = textwrap.dedent("""
         return tttp_ctx(s, list(fs), ctx).values
     got = jax.jit(shard_map(d_tttp, mesh=mesh,
                             in_specs=(st_spec, (f_spec,) * 3),
-                            out_specs=P("data"), check_rep=False))(
+                            out_specs=P("data"), check_vma=False))(
         st, tuple(factors))
     want = tttp_ctx(st, factors, LOCAL).values
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -60,7 +61,7 @@ _SCRIPT = textwrap.dedent("""
         return mttkrp_ctx(s, [None, fs[1], fs[2]], 0, ctx)
     got = jax.jit(shard_map(d_mttkrp, mesh=mesh,
                             in_specs=(st_spec, (f_spec,) * 3),
-                            out_specs=P(None, "model"), check_rep=False))(
+                            out_specs=P(None, "model"), check_vma=False))(
         st, tuple(factors))
     want = mttkrp_ctx(st, [None, factors[1], factors[2]], 0, LOCAL)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -72,7 +73,7 @@ _SCRIPT = textwrap.dedent("""
         return tuple(als_sweep(s, o, list(fs), 1e-6, cg_iters=12, ctx=ctx))
     got = jax.jit(shard_map(d_als, mesh=mesh,
                             in_specs=(st_spec, st_spec, (f_spec,) * 3),
-                            out_specs=(f_spec,) * 3, check_rep=False))(
+                            out_specs=(f_spec,) * 3, check_vma=False))(
         st, omega, tuple(factors))
     want = als_sweep(st, omega, list(factors), 1e-6, cg_iters=12, ctx=LOCAL)
     for g, w in zip(got, want):
@@ -90,7 +91,7 @@ _SCRIPT = textwrap.dedent("""
                            matvec_path="auto")
     got = jax.jit(shard_map(d_gram, mesh=mesh,
                             in_specs=(st_spec, (f_spec,) * 3, f_spec),
-                            out_specs=P(None, "model"), check_rep=False))(
+                            out_specs=P(None, "model"), check_vma=False))(
         omega, tuple(factors), x0)
     want = gram_matvec(omega, factors, 0, x0, lam=1e-6, ctx=LOCAL,
                        matvec_path="auto")
@@ -109,10 +110,10 @@ _SCRIPT = textwrap.dedent("""
         local = SparseTensor(idx[0], vals[0], valid[0], (32, 8), None)
         out = sparse_allreduce_butterfly(local, "x")
         return out.todense()
-    mesh1 = jax.make_mesh((8,), ("x",))
+    mesh1 = make_mesh((8,), ("x",))
     got = jax.jit(shard_map(d_butterfly, mesh=mesh1,
                             in_specs=(P("x"), P("x"), P("x")),
-                            out_specs=P("x"), check_rep=False))(
+                            out_specs=P("x"), check_vma=False))(
         idx, vals, valid)
     want = np.asarray(sum(b.todense() for b in blocks))
     got0 = np.asarray(got).reshape(8, 32, 8)
@@ -126,7 +127,7 @@ _SCRIPT = textwrap.dedent("""
         out, err = compressed_psum(g[0], jnp.zeros_like(g[0]), "x")
         return out
     got = jax.jit(shard_map(d_comp, mesh=mesh1, in_specs=P("x"),
-                            out_specs=P("x"), check_rep=False))(g)
+                            out_specs=P("x"), check_vma=False))(g)
     want = g.sum(0)
     rel = float(jnp.max(jnp.abs(got[:64] - want)) /
                 (jnp.max(jnp.abs(want)) + 1e-9))
@@ -142,9 +143,8 @@ def test_distributed_equivalence_subprocess(tmp_path):
     script = tmp_path / "dist_check.py"
     script.write_text(_SCRIPT)
     env = dict(os.environ)
-    # force the host (CPU) platform: the XLA_FLAGS device-count override only
-    # applies to it, and letting jax probe an accelerator plugin here burns
-    # minutes in init retries on accelerator-less CI machines
+    # force the host (CPU) platform: the XLA_FLAGS device-count override
+    # applies only to it, whatever accelerator the machine has
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -161,7 +161,8 @@ _ROWSHARD_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.core.distributed import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core.sparse_tensor import SparseTensor
     from repro.core.distributed import (AxisCtx, multilinear_rowsharded,
@@ -170,7 +171,7 @@ _ROWSHARD_SCRIPT = textwrap.dedent("""
     from repro.sparse import ops as sops
     from repro.data.synthetic import shuffle_and_pad
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ctx = AxisCtx(data="data", model=None)
     key = jax.random.PRNGKey(0)
     I, J, K, R, m = 64, 48, 32, 8, 2000
@@ -185,7 +186,7 @@ _ROWSHARD_SCRIPT = textwrap.dedent("""
     got = jax.jit(shard_map(
         lambda s, fs: multilinear_rowsharded(s, list(fs), ctx, h_slices=2),
         mesh=mesh, in_specs=(st_spec, (f_spec,) * 3), out_specs=P("data"),
-        check_rep=False))(st, tuple(factors))
+        check_vma=False))(st, tuple(factors))
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(multilinear_values(st, factors)),
                                rtol=1e-4, atol=1e-4)
@@ -193,7 +194,7 @@ _ROWSHARD_SCRIPT = textwrap.dedent("""
     got2 = jax.jit(shard_map(
         lambda s, fs: mttkrp_rowsharded(s, list(fs), 0, ctx, h_slices=2),
         mesh=mesh, in_specs=(st_spec, (f_spec,) * 3),
-        out_specs=P("data", None), check_rep=False))(st, tuple(factors))
+        out_specs=P("data", None), check_vma=False))(st, tuple(factors))
     want2 = sops.mttkrp(st, [None, factors[1], factors[2]], 0)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(want2),
                                rtol=1e-4, atol=1e-4)

@@ -237,3 +237,56 @@ def test_cg_matvec_bf16_accumulates_fp32():
                                    num_rows=64, use_pallas=False)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **BF16_TOL)
+
+
+# ---------------------------------------------------------------------------
+# dispatch rule (kops.route): the TPU branch is steered by patching the
+# probed platform; nothing here needs a chip
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env,want", [(None, "xla"), ("0", "xla"),
+                                      ("1", "pallas")])
+def test_route_on_cpu(monkeypatch, env, want):
+    monkeypatch.setattr(kops, "_platform", lambda: "cpu")
+    if env is None:
+        monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_USE_PALLAS", env)
+    assert all(kops.route(f) == want for f in ("tttp", "mttkrp", "cg_matvec"))
+    assert kops.route("tttp", use_pallas=True) == "pallas"
+    assert kops.route("tttp", use_pallas=False) == "xla"
+
+
+@pytest.mark.parametrize("family", sorted(kops.TPU_REFUSED))
+def test_route_on_tpu_refused_family(monkeypatch, family):
+    """A kernel the TPU compiler refuses takes XLA by default; asking for it
+    raises with the compiler's reason instead of interpreting it."""
+    monkeypatch.setattr(kops, "_platform", lambda: "tpu")
+    monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    assert kops.route(family) == "xla"
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        kops.route(family, use_pallas=True)
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    with pytest.raises(RuntimeError, match="refuses"):
+        kops.route(family)
+
+
+def test_route_on_tpu_compiling_family(monkeypatch):
+    monkeypatch.setattr(kops, "_platform", lambda: "tpu")
+    monkeypatch.setattr(kops, "TPU_REFUSED", {})
+    monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    assert kops.route("tttp") == "pallas"
+    assert kops.route("tttp", use_pallas=False) == "xla"
+    assert not kops._interpret()
+
+
+def test_no_interpret_mode_on_tpu(monkeypatch):
+    """On a TPU an explicit Pallas request for a refused kernel raises
+    before any kernel is built: interpret mode is never reached."""
+    monkeypatch.setattr(kops, "_platform", lambda: "tpu")
+    st, factors = _mk(jax.random.PRNGKey(5), (13, 9, 7), 50, 8, jnp.float32)
+    with pytest.raises(RuntimeError, match="tttp"):
+        kops.tttp_values(st, factors, use_pallas=True)
+    got = kops.tttp_values(st, factors)
+    want = kops.tttp_values(st, factors, use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -290,3 +290,28 @@ def test_tracing_overhead_under_two_percent():
     assert traced <= base * 1.02 + 2e-3, (traced, base)
     reg = obs.get_registry().summary()
     assert reg["timings"]["sweep"]["count"] in (50, 100)  # 10 sweeps x reps
+
+
+@pytest.mark.parametrize("family", ["mttkrp", "cg_matvec"])
+def test_trace_time_fallback_bumps_counter(family):
+    """Under jit the cached bucket pattern does not cross the tracer
+    boundary: the bucketed/fused path falls back, and says so once per
+    trace in a ``dispatch/fallback/*`` counter."""
+    from repro import planner
+    from repro.core.sparse_tensor import SparseTensor
+
+    st = SparseTensor.random(jax.random.PRNGKey(3), (20, 15, 10), 150)
+    fs = [jax.random.normal(jax.random.PRNGKey(40 + i), (d, 4))
+          for i, d in enumerate(st.shape)]
+    if family == "mttkrp":
+        fn = jax.jit(lambda s, f: planner.planned_mttkrp(
+            s, [None, f[1], f[2]], 0, path="bucketed"))
+        name = "dispatch/fallback/mttkrp_bucketed"
+    else:
+        fn = jax.jit(lambda s, f: planner.planned_cg_matvec(
+            s, list(f), 0, f[0], path="fused"))
+        name = "dispatch/fallback/cg_matvec_fused"
+    obs.enable()
+    fn(st, fs)
+    fn(st, fs)                                  # cached: no second trace
+    assert obs.get_registry().summary()["counters"][name] == 1

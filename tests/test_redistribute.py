@@ -75,7 +75,8 @@ _DIST_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.core.distributed import make_mesh
     from jax.sharding import PartitionSpec as P
 
     from repro.core.sparse_tensor import SparseTensor
@@ -83,7 +84,7 @@ _DIST_SCRIPT = textwrap.dedent("""
     from repro.sparse import redistribute
     from repro.data.synthetic import shuffle_and_pad
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     key = jax.random.PRNGKey(0)
 
     # 1) sharded transpose_distributed == local dense transpose (the global
@@ -114,7 +115,7 @@ _DIST_SCRIPT = textwrap.dedent("""
         return sparse_allreduce_butterfly(local, "data").todense()
     got = jax.jit(shard_map(d_butterfly, mesh=mesh,
                             in_specs=(P("data"), P("data"), P("data")),
-                            out_specs=P("data"), check_rep=False))(
+                            out_specs=P("data"), check_vma=False))(
         idx, vals, valid)
     want = np.asarray(sum(b.todense() for b in blocks))
     got = np.asarray(got).reshape(4, 16, 8)

@@ -157,16 +157,40 @@ def test_compression_error_feedback_converges():
     # covered in the distributed subprocess test; here check quantizer error
     # feedback: repeated compression of a constant recovers it on average.
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.core.distributed import make_mesh
     from jax.sharding import PartitionSpec as P
     g = jnp.array([1.234e-3] * 64)
     err = jnp.zeros_like(g)
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     f = shard_map(lambda gg, ee: compressed_psum(gg, ee, "x"),
                   mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-                  check_rep=False)
+                  check_vma=False)
     acc = jnp.zeros_like(g)
     for _ in range(20):
         out, err = f(g, err)
         acc = acc + out
     np.testing.assert_allclose(acc / 20, g, rtol=5e-2)
+
+
+def test_resume_prints_step(tmp_path, capsys):
+    loop = RestartableLoop(str(tmp_path), lambda i, s: s + 1, ckpt_every=2)
+    loop.run(jnp.zeros(1), 4)
+    capsys.readouterr()
+    state = RestartableLoop(str(tmp_path), lambda i, s: s + 1).run(
+        jnp.zeros(1), 6)
+    assert float(state[0]) == 6.0
+    assert "resumed from checkpoint step 3" in capsys.readouterr().out
+
+
+def test_resume_raises_on_other_state_structure(tmp_path):
+    """Another solver's checkpoint in the directory is not corruption: the
+    loop must not fall back to step 0 and silently start over."""
+    from repro.checkpoint.checkpointer import StructureMismatch
+
+    RestartableLoop(str(tmp_path), lambda i, s: s).run(
+        (jnp.zeros(3), jnp.zeros(2)), 2)
+    loop = RestartableLoop(str(tmp_path), lambda i, s: s)
+    with pytest.raises(StructureMismatch, match="structure drift"):
+        loop.run({"factors": (jnp.zeros(3), jnp.zeros(2)),
+                  "damping": jnp.zeros(())}, 2)

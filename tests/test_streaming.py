@@ -276,13 +276,14 @@ def test_sorted_mode_fast_path_matches_unsorted():
 _MESH_SCRIPT = r"""
 import jax, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.core.distributed import make_mesh
 from repro.data import streaming
 from repro.data.pipeline import CompletionDataset
 from repro.core.completion import als_sweep
 from repro.core.distributed import DistLayout
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 shape = (40, 32, 12)
 chunks = list(streaming.function_stream(5, shape, 8000, 2000))
 ds = CompletionDataset.from_stream(iter(chunks), shape, mesh=mesh,
@@ -294,7 +295,7 @@ fs = [jax.random.normal(k, (d, 4))
 fn = jax.jit(shard_map(
     lambda s, o, f: tuple(als_sweep(s, o, list(f), 1e-4, ctx=layout.ctx)),
     mesh=mesh, in_specs=(st_spec, st_spec, (P(None, None),) * 3),
-    out_specs=(P(None, None),) * 3, check_rep=False))
+    out_specs=(P(None, None),) * 3, check_vma=False))
 out = fn(ds.tensor, ds.omega, tuple(fs))
 ds_l = CompletionDataset.from_stream(iter(chunks), shape, num_shards=1,
                                      bucket_modes=())
