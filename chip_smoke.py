@@ -81,7 +81,7 @@ class Smoke:
 
     def train(self, tag: str, algorithm: str, sweeps: int, *extra: str):
         """One ``complete.main`` run with a fresh checkpoint directory;
-        prints the kernel routes, dispatch counters and timings."""
+        prints the kernel routes, dispatch fallback counters and timings."""
         self.obs.get_registry().reset()
         with tempfile.TemporaryDirectory() as ckpt:
             res = self.complete.main(complete_argv(ckpt, algorithm, sweeps,
@@ -92,7 +92,7 @@ class Smoke:
             self.obs.get_registry().summary()["counters"].items())}
         steady = [h[1] for h in hist[1:]] or [h[1] for h in hist]
         print(f"[{tag}] kernel routes: {routes}")
-        print(f"[{tag}] trace-time kernel/dispatch counters: {counters}")
+        print(f"[{tag}] trace-time dispatch fallback counters: {counters}")
         print(f"[{tag}] compile {res['compile_seconds']:.3f} s; "
               f"{len(hist)} sweeps; steady "
               f"{statistics.median(steady):.3f} s/sweep")

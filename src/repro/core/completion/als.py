@@ -21,6 +21,7 @@ from typing import List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.distributed import (AxisCtx, LOCAL, mttkrp_ctx, rowdot_ctx,
                                     tttp_ctx)
 from repro.core.sparse_tensor import SparseTensor
@@ -69,19 +70,23 @@ def gram_matvec(omega: SparseTensor, factors: Sequence[jax.Array], mode: int,
     from repro.core.tttp import multilinear_values
     r = x.shape[1]
     rs = -(-r // h_slices)
-    acc = jnp.zeros((omega.cap,), omega.values.dtype)
-    for h in range(h_slices):
-        sl = [None if f is None else f[:, h * rs:(h + 1) * rs] for f in fs]
-        acc = acc + multilinear_values(omega, sl)
-    z = omega.with_values(omega.values * ctx.psum_model(acc))
+    with obs.scope("tttp"):
+        acc = jnp.zeros((omega.cap,), omega.values.dtype)
+        for h in range(h_slices):
+            sl = [None if f is None else f[:, h * rs:(h + 1) * rs]
+                  for f in fs]
+            acc = acc + multilinear_values(omega, sl)
+        z = omega.with_values(omega.values * ctx.psum_model(acc))
     fs[mode] = None
     from repro.planner import mttkrp_fn
     mv_kernel = mttkrp_fn(mttkrp_path)
-    cols = []
-    for h in range(h_slices):
-        sl = [None if f is None else f[:, h * rs:(h + 1) * rs] for f in fs]
-        cols.append(mv_kernel(z, sl, mode))
-    y = ctx.psum_data(jnp.concatenate(cols, axis=1)[:, :r])
+    with obs.scope("mttkrp"):
+        cols = []
+        for h in range(h_slices):
+            sl = [None if f is None else f[:, h * rs:(h + 1) * rs]
+                  for f in fs]
+            cols.append(mv_kernel(z, sl, mode))
+        y = ctx.psum_data(jnp.concatenate(cols, axis=1)[:, :r])
     return y + lam * x
 
 
@@ -95,35 +100,46 @@ def batched_pcg(matvec, b: jax.Array, x0: jax.Array, precond=None,
     block-Jacobi when M is each row's block diagonal; ``None`` is the
     identity (plain CG). Stops (whole batch) when every row residual²
     ≤ tol²·‖b_row‖², or at max_iters (≤ R guarantees exact solve modulo
-    roundoff, §2.2)."""
+    roundoff, §2.2). Returns ``(x, steps)``: the solution and the
+    ``while_loop`` steps taken (int32).
+
+    Each ``matvec`` call runs under ``obs.scope("matvec")``, the dense row
+    updates and row dots under ``obs.scope("cg_update")``."""
     if precond is None:
         precond = lambda v: v
-    bnorm2 = rowdot_ctx(b, b, ctx)
-    thresh = (tol ** 2) * jnp.maximum(bnorm2, 1e-30)
-
-    r0 = b - matvec(x0)
-    z0 = precond(r0)
+    with obs.scope("cg_update"):
+        bnorm2 = rowdot_ctx(b, b, ctx)
+        thresh = (tol ** 2) * jnp.maximum(bnorm2, 1e-30)
+    with obs.scope("matvec"):
+        ax0 = matvec(x0)
+    with obs.scope("cg_update"):
+        r0 = b - ax0
+        z0 = precond(r0)
+        init = (jnp.int32(0), x0, r0, z0, rowdot_ctx(r0, z0, ctx),
+                rowdot_ctx(r0, r0, ctx))
 
     def cond(state):
         i, x, r, p, rz, rs = state
-        return (i < max_iters) & jnp.any(rs > thresh)
+        with obs.scope("cg_update"):
+            return (i < max_iters) & jnp.any(rs > thresh)
 
     def body(state):
         i, x, r, p, rz, rs = state
-        ap = matvec(p)
-        pap = rowdot_ctx(p, ap, ctx)
-        active = rs > thresh
-        alpha = jnp.where(active, rz / jnp.where(pap > 0, pap, 1.0), 0.0)
-        x = x + alpha[:, None] * p
-        r = r - alpha[:, None] * ap
-        z = precond(r)
-        rz_new = rowdot_ctx(r, z, ctx)
-        beta = jnp.where(active, rz_new / jnp.where(rz != 0, rz, 1.0), 0.0)
-        p = z + beta[:, None] * p
-        return i + 1, x, r, p, rz_new, rowdot_ctx(r, r, ctx)
+        with obs.scope("matvec"):
+            ap = matvec(p)
+        with obs.scope("cg_update"):
+            pap = rowdot_ctx(p, ap, ctx)
+            active = rs > thresh
+            alpha = jnp.where(active, rz / jnp.where(pap > 0, pap, 1.0), 0.0)
+            x = x + alpha[:, None] * p
+            r = r - alpha[:, None] * ap
+            z = precond(r)
+            rz_new = rowdot_ctx(r, z, ctx)
+            beta = jnp.where(active, rz_new / jnp.where(rz != 0, rz, 1.0),
+                             0.0)
+            p = z + beta[:, None] * p
+            return i + 1, x, r, p, rz_new, rowdot_ctx(r, r, ctx)
 
-    init = (jnp.int32(0), x0, r0, z0, rowdot_ctx(r0, z0, ctx),
-            rowdot_ctx(r0, r0, ctx))
     iters, x, r, p, rz, rs = jax.lax.while_loop(cond, body, init)
     return x, iters
 
@@ -139,18 +155,40 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
                     factors: List[jax.Array], mode: int, lam: float,
                     cg_tol: float = 1e-4, cg_iters: int = 32,
                     ctx: AxisCtx = LOCAL, h_slices: int = 1,
-                    mttkrp_path: Optional[str] = None) -> jax.Array:
-    """One ALS factor update by implicit CG. ``mttkrp_path`` opts the
-    MTTKRP contractions into planner dispatch (repro.planner)."""
+                    mttkrp_path: Optional[str] = None):
+    """One ALS factor update by implicit CG; returns ``(factor, CG
+    steps)``. The right-hand-side MTTKRP runs under ``obs.scope("rhs")``.
+    ``mttkrp_path`` opts the MTTKRP contractions into planner dispatch
+    (repro.planner)."""
     fs = list(factors)
     fs[mode] = None
-    b = mttkrp_ctx(st, fs, mode, ctx, path=mttkrp_path)
+    with obs.scope("rhs"):
+        b = mttkrp_ctx(st, fs, mode, ctx, path=mttkrp_path)
     mv = functools.partial(gram_matvec, omega, factors, mode, lam=lam,
                            ctx=ctx, h_slices=h_slices,
                            mttkrp_path=mttkrp_path)
-    x, _ = batched_cg(mv, b, factors[mode], tol=cg_tol, max_iters=cg_iters,
+    return batched_cg(mv, b, factors[mode], tol=cg_tol, max_iters=cg_iters,
                       ctx=ctx)
-    return x
+
+
+def als_sweep_stats(st: SparseTensor, omega: SparseTensor,
+                    factors: Sequence[jax.Array], lam: float,
+                    cg_tol: float = 1e-4, cg_iters: int = 32,
+                    ctx: AxisCtx = LOCAL, h_slices: int = 1,
+                    mttkrp_path: Optional[str] = None):
+    """:func:`als_sweep` that also returns its solver counter:
+    ``(factors, cg_steps)``, with ``cg_steps`` an ``int32[N]`` of the CG
+    steps each mode's update ran. Mode ``d``'s update runs under
+    ``obs.scope(f"mode_{d}")``."""
+    fs = list(factors)
+    steps = []
+    for d in range(st.ndim):
+        with obs.scope(f"mode_{d}"):
+            fs[d], n = als_update_mode(st, omega, fs, d, lam, cg_tol,
+                                       cg_iters, ctx, h_slices,
+                                       mttkrp_path=mttkrp_path)
+        steps.append(n)
+    return fs, jnp.stack(steps)
 
 
 def als_sweep(st: SparseTensor, omega: SparseTensor,
@@ -159,11 +197,8 @@ def als_sweep(st: SparseTensor, omega: SparseTensor,
               ctx: AxisCtx = LOCAL, h_slices: int = 1,
               mttkrp_path: Optional[str] = None) -> List[jax.Array]:
     """Full ALS sweep (all modes, in order) — paper Algorithm of §2.2."""
-    fs = list(factors)
-    for d in range(st.ndim):
-        fs[d] = als_update_mode(st, omega, fs, d, lam, cg_tol, cg_iters,
-                                ctx, h_slices, mttkrp_path=mttkrp_path)
-    return fs
+    return als_sweep_stats(st, omega, factors, lam, cg_tol, cg_iters, ctx,
+                           h_slices, mttkrp_path)[0]
 
 
 # ---------------------------------------------------------------------------

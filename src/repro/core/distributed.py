@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.sparse_tensor import SparseTensor
 from repro.core.utils import axis_size
 from repro.sparse import ops as sops
@@ -45,10 +46,16 @@ class AxisCtx:
     model: Optional[str] = None
 
     def psum_data(self, x):
-        return jax.lax.psum(x, self.data) if self.data is not None else x
+        if self.data is None:
+            return x
+        with obs.scope("psum"):
+            return jax.lax.psum(x, self.data)
 
     def psum_model(self, x):
-        return jax.lax.psum(x, self.model) if self.model is not None else x
+        if self.model is None:
+            return x
+        with obs.scope("psum"):
+            return jax.lax.psum(x, self.model)
 
     def data_size(self) -> int:
         if self.data is None:

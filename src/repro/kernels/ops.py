@@ -91,9 +91,7 @@ def route(family: str, use_pallas: Optional[bool] = None) -> str:
 
 
 def _use_pallas(family: str, use_pallas: Optional[bool]) -> bool:
-    r = route(family, use_pallas)
-    obs.counter_add(f"kernel/{family}/{r}")
-    return r == "pallas"
+    return route(family, use_pallas) == "pallas"
 
 
 def _interpret() -> bool:

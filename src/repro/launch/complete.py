@@ -128,9 +128,10 @@ def main(argv=None) -> dict:
     from jax.sharding import PartitionSpec as P
 
     from repro.core import losses as LOSS
-    from repro.core.completion import (als_sweep, ccd_sweep, ccd_sweep_tttp,
-                                       gcp_adam_init, gcp_step, ggn_init,
-                                       ggn_sweep, sgd_sweep)
+    from repro.core.completion import (als_sweep_stats, ccd_sweep,
+                                       ccd_sweep_tttp, gcp_adam_init,
+                                       gcp_step, ggn_init, ggn_sweep,
+                                       sgd_sweep)
     from repro.core.completion.gcp import AdamState
     from repro.core.completion.ccd import residual_values
     from repro.core.completion.gauss_newton import GGNState
@@ -236,11 +237,15 @@ def main(argv=None) -> dict:
     else:
         st_spec = fs_spec = None
 
+    # an ALS sweep also returns each mode's CG steps (its solver counter),
+    # printed on the sweep's line
+    counts_steps = args.algorithm == "als"
     if args.algorithm == "als":
-        fn = wrap(lambda s, o, fs: tuple(als_sweep(
-                      s, o, list(fs), args.lam, cg_tol=args.cg_tol,
-                      cg_iters=args.cg_iters, ctx=ctx)),
-                  (st_spec, st_spec, fs_spec), fs_spec)
+        fn = wrap(lambda s, o, fs: (lambda f, n: (tuple(f), n))(
+                      *als_sweep_stats(s, o, list(fs), args.lam,
+                                       cg_tol=args.cg_tol,
+                                       cg_iters=args.cg_iters, ctx=ctx)),
+                  (st_spec, st_spec, fs_spec), (fs_spec, P()))
         state0 = tuple(factors)
         call_args = lambda i, fs: (st, omega, tuple(fs))
     elif args.algorithm in ("ccd", "ccd_tttp"):
@@ -310,9 +315,13 @@ def main(argv=None) -> dict:
         state = fn(*call_args(i, state))
         jax.block_until_ready(state)
         dt = time.perf_counter() - t0
+        note = ""
+        if counts_steps:
+            state, steps = state
+            note = f"  cg_steps={np.asarray(steps).tolist()}"
         e = train_rmse(st, get_factors(state))
         hist.append((i, dt, e))
-        print(f"sweep {i:3d}  {dt*1e3:8.1f} ms  rmse={e:.6f}")
+        print(f"sweep {i:3d}  {dt*1e3:8.1f} ms  rmse={e:.6f}{note}")
         return state
 
     loop = RestartableLoop(args.ckpt_dir, loop_step, ckpt_every=5)

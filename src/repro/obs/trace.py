@@ -14,7 +14,15 @@ Timing discipline: a live span's duration is wall time between ``__enter__``
 and ``__exit__``; for device work the caller must fence the result
 (``sp.fence(out)``) so async dispatch doesn't end the span early. Every
 finished span feeds the registry's timing histogram under its slash-joined
-path and, when a JSONL sink is installed, emits one flat event line.
+path and, when a JSONL sink is installed, emits one flat event line. A live
+span is also a ``jax.profiler.TraceAnnotation`` named by its path, so under
+``jax.profiler`` it lands on the trace's host plane, on the device's clock.
+
+``scope(name)`` names traced device work instead: a ``jax.named_scope``
+that puts ``name`` into the ``op_name`` metadata of every operation traced
+under it, so a compiled program's operations (and the profiler's device
+events for them) say which kernel family and solver phase they belong to.
+It changes metadata only, so it is always on and costs nothing at run time.
 """
 from __future__ import annotations
 
@@ -140,13 +148,15 @@ def span(name: str, **attrs) -> Iterator[Any]:
     if not _ENABLED or not _trace_clean():
         yield _NOOP
         return
+    import jax               # loaded already: _trace_clean imported it
     st = _stack()
     path = (st[-1].path + "/" + name) if st else name
     sp = Span(name, path, attrs)
     st.append(sp)
     t0 = time.perf_counter()
     try:
-        yield sp
+        with jax.profiler.TraceAnnotation(path):
+            yield sp
     finally:
         dur = time.perf_counter() - t0
         st.pop()
@@ -167,6 +177,15 @@ def span(name: str, **attrs) -> Iterator[Any]:
             flat.pop("children", None)
             flat["depth"] = len(st) + 1          # 1-based: roots at depth 1
             _SINK.emit(flat)
+
+
+def scope(name: str):
+    """Name the device work traced inside the block (module docstring):
+    ``with obs.scope("mttkrp"): ...`` makes every operation traced there
+    carry ``.../mttkrp/...`` in its HLO ``op_name``. Scopes nest, as the
+    ``jax.named_scope`` this is."""
+    import jax
+    return jax.named_scope(name)
 
 
 def counter_add(name: str, value: float = 1.0) -> None:

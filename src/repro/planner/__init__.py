@@ -12,6 +12,10 @@ Layering::
 ``repro.core.api.einsum`` and ``api.TTTP`` are thin shims over
 :func:`planned_einsum`; the completion solvers opt in through the
 ``path=`` overrides of :func:`planned_mttkrp` / :func:`planned_tttp`.
+Each of :func:`planned_tttp`, :func:`planned_mttkrp` and
+:func:`planned_cg_matvec` runs under the ``obs.scope`` of its kernel
+family (``tttp``, ``mttkrp``, ``cg_matvec``), so every solver's compiled
+operations name the family they belong to.
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 
+from repro import obs
 from repro.core.distributed import AxisCtx, LOCAL
 from repro.core.sparse_tensor import SparseTensor
 from repro.planner import config as _pconfig
@@ -109,8 +114,9 @@ def planned_mttkrp(st: SparseTensor, factors: Sequence[Optional[jax.Array]],
     ops = (st, *[factors[d] for d in present])
     if h_slices != 1:
         config = (config or _pconfig.default_config()).with_h_slices(h_slices)
-    return planned_einsum(expr, *ops, path=path, autotune=autotune,
-                          ctx=ctx, rowsharded=rowsharded, config=config)
+    with obs.scope("mttkrp"):
+        return planned_einsum(expr, *ops, path=path, autotune=autotune,
+                              ctx=ctx, rowsharded=rowsharded, config=config)
 
 
 def planned_reduce(st: SparseTensor, keep_modes: Tuple[int, ...],
@@ -152,8 +158,9 @@ def planned_cg_matvec(weights: SparseTensor,
     expr = ",".join(terms) + "->" + s_term[mode] + _RANK_LETTER
     ops = (weights, *[factors[d] for d in others], x,
            *[factors[d] for d in others])
-    return planned_einsum(expr, *ops, path=path, autotune=autotune,
-                          ctx=ctx, config=config)
+    with obs.scope("cg_matvec"):
+        return planned_einsum(expr, *ops, path=path, autotune=autotune,
+                              ctx=ctx, config=config)
 
 
 def planned_tttp(st: SparseTensor, factors: Sequence[Optional[jax.Array]],
@@ -174,5 +181,6 @@ def planned_tttp(st: SparseTensor, factors: Sequence[Optional[jax.Array]],
     ops = (st, *[fs[d] for d in present])
     if h_slices != 1:
         config = (config or _pconfig.default_config()).with_h_slices(h_slices)
-    return planned_einsum(expr, *ops, path=path, autotune=autotune,
-                          ctx=ctx, rowsharded=rowsharded, config=config)
+    with obs.scope("tttp"):
+        return planned_einsum(expr, *ops, path=path, autotune=autotune,
+                              ctx=ctx, rowsharded=rowsharded, config=config)

@@ -6,6 +6,7 @@ contractions dispatched through ``planner.execute`` (ISSUE 3 acceptance).
 Subprocesses (one jax init each) because the forced-device XLA flag must be
 set before jax initializes, and the main test process keeps the
 single-device view per the harness contract."""
+import json
 import os
 import subprocess
 import sys
@@ -76,6 +77,21 @@ def test_main_in_process_runs_every_sweep(tmp_path):
     assert res["compile_seconds"] > 0
     assert complete.train_rmse(res["tensor"], res["factors"]) == \
         pytest.approx(res["history"][-1][2], rel=1e-6)
+
+
+def test_als_sweep_line_names_each_modes_cg_steps(tmp_path, capsys):
+    """An ALS sweep's line carries the CG steps each mode ran."""
+    from repro.launch import complete
+
+    complete.main(["--dataset", "function", "--dims", "30,20,10",
+                   "--nnz", "800", "--rank", "4", "--sweeps", "2",
+                   "--cg-iters", "7", "--ckpt-dir", str(tmp_path)])
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("sweep ")]
+    assert len(lines) == 2
+    for ln in lines:
+        steps = json.loads(ln.split("cg_steps=")[1])
+        assert len(steps) == 3 and all(0 <= n <= 7 for n in steps), ln
 
 
 @pytest.mark.parametrize("env", [None, "elsewhere"])

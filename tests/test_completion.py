@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core import losses as L
-from repro.core.completion import (als_sweep, als_sweep_explicit, ccd_sweep,
+from repro.core.completion import (als_sweep, als_sweep_explicit,
+                                   als_sweep_stats, ccd_sweep,
                                    ccd_sweep_tttp, gcp_adam_init, gcp_step,
                                    sgd_sweep)
 from repro.core.completion.ccd import residual_values
@@ -52,6 +53,28 @@ def test_als_cg_converges_and_matches_explicit():
         st, *fs)
     for a, b in zip(f1, f2):
         np.testing.assert_allclose(a, b, rtol=3e-2, atol=3e-2)
+
+
+def test_als_sweep_stats_counts_cg_steps_and_matches_als_sweep():
+    """``als_sweep_stats`` returns ``als_sweep``'s factors bit for bit, and
+    each mode's CG steps: the step bound where it binds, fewer where the
+    tolerance stops CG first."""
+    st, fs = make_problem(jax.random.PRNGKey(2))
+    omega = st.with_values(jnp.ones_like(st.values))
+
+    def both(tol, iters):
+        plain = jax.jit(lambda s, o, f: als_sweep(
+            s, o, list(f), 1e-4, cg_tol=tol, cg_iters=iters))(st, omega, fs)
+        stats, steps = jax.jit(lambda s, o, f: als_sweep_stats(
+            s, o, list(f), 1e-4, cg_tol=tol, cg_iters=iters))(st, omega, fs)
+        for a, b in zip(plain, stats):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert steps.dtype == jnp.int32 and steps.shape == (st.ndim,)
+        return np.asarray(steps).tolist()
+
+    assert both(1e-12, 3) == [3, 3, 3]
+    steps = both(1e-3, 30)
+    assert all(1 <= n < 30 for n in steps), steps
 
 
 def test_ccd_variants_identical_and_converge():

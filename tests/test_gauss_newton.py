@@ -163,8 +163,8 @@ def test_ggn_update_mode_matches_als_for_quadratic():
     st, fs = _problem(key, shape=shape, nnz=300, r=4)
     omega = st.with_values(jnp.ones_like(st.values))
     lam = 1e-4
-    want = als_update_mode(st, omega, list(fs), 0, lam, cg_tol=1e-8,
-                           cg_iters=60)
+    want, _ = als_update_mode(st, omega, list(fs), 0, lam, cg_tol=1e-8,
+                              cg_iters=60)
     got = ggn_update_mode(st, list(fs), 0, L.quadratic, lam, damping=0.0,
                           cg_tol=1e-8, cg_iters=60)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)

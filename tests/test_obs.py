@@ -1,8 +1,13 @@
 """Telemetry subsystem tests (DESIGN.md §11): span nesting + aggregation,
 JSONL round-trip, jit-safety of the disabled path, planner plan records,
-ingest gauges, and the measured-overhead bound on a real ALS run."""
+ingest gauges, spans on the profiler's trace, the cost of a live span, and
+the named scopes on a compiled ALS sweep."""
+import glob
 import json
+import math
 import os
+import re
+import statistics
 import time
 
 import jax
@@ -241,55 +246,146 @@ def test_ingest_telemetry(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# overhead bound: tracing a real 10-sweep ALS run costs <2%
+# overhead bound: a live span costs under 2% of the steps it wraps
 # ---------------------------------------------------------------------------
 
+# The program's shortest spanned step is an eager kernel call of a few
+# milliseconds; 2% of a 5 ms step is 100 us. A live span measures about
+# 10 us on a CPU container; the budget leaves room for a loaded machine.
+SPAN_BUDGET_S = 100e-6
+
+
 def test_tracing_overhead_under_two_percent():
-    from repro.core.completion import als_sweep
-    from repro.core.sparse_tensor import SparseTensor
+    """The live path's cost per span (enter, fence on a ready array, exit,
+    registry and profiler annotation), the median of several repeats of a
+    few thousand spans: a bound that load from other processes does not
+    swing the way a comparison of two timed runs does."""
+    x = jax.block_until_ready(jnp.ones(8))
 
-    st = SparseTensor.random(jax.random.PRNGKey(3), (60, 50, 40), 4000)
-    omega = st.with_values(jnp.ones_like(st.values))
-    fs0 = [jax.random.normal(jax.random.PRNGKey(20 + i), (d, 6)) / 6 ** 0.5
-           for i, d in enumerate(st.shape)]
-    step = jax.jit(lambda fs: tuple(als_sweep(st, omega, list(fs), 1e-3,
-                                              cg_iters=4)))
-
-    def run_sweeps():
-        fs = tuple(fs0)
-        for i in range(10):
-            with obs.span("sweep", i=i) as sp:
-                fs = step(fs)
-                sp.fence(fs)
-        jax.block_until_ready(fs)
-        return fs
-
-    run_sweeps()                                   # compile once
-    def best_of(n):
-        best = float("inf")
+    def per_span(n=2000):
+        t0 = time.perf_counter()
         for _ in range(n):
-            t0 = time.perf_counter()
-            run_sweeps()
-            best = min(best, time.perf_counter() - t0)
-        return best
+            with obs.span("step") as sp:
+                sp.fence(x)
+        return (time.perf_counter() - t0) / n
 
-    obs.disable()
-    base = best_of(5)
     obs.enable()
-    traced = best_of(5)
-    obs.disable()
-    # 2% of a ~100ms 10-sweep run is ~2ms of timer noise territory on a
-    # shared container — allow a small absolute epsilon alongside the bound
-    if traced > base * 1.02 + 2e-3:
-        # noise is one-sided (other tenants only slow you down): re-measure
-        # both arms once before declaring a real tracing regression
-        base = min(base, best_of(5))
-        obs.enable()
-        traced = min(traced, best_of(5))
-        obs.disable()
-    assert traced <= base * 1.02 + 2e-3, (traced, base)
-    reg = obs.get_registry().summary()
-    assert reg["timings"]["sweep"]["count"] in (50, 100)  # 10 sweeps x reps
+    per_span(200)                                  # warm the path
+    cost = statistics.median(per_span() for _ in range(7))
+    assert cost < SPAN_BUDGET_S, cost
+    assert obs.get_registry().summary()["timings"]["step"]["count"] == \
+        200 + 7 * 2000
+
+
+def test_live_span_lands_on_the_profiler_trace(tmp_path):
+    """A live span is also a profiler annotation: it shows on a ``/host:``
+    plane of a ``jax.profiler`` trace under its path."""
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.span("outer"):
+        with obs.span("inner") as sp:
+            sp.fence(jnp.arange(4.0) * 2.0)
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"outer", "outer/inner"} <= names
+
+
+def test_kernel_dispatch_bumps_no_counter():
+    """Kernel routing leaves no trace-time counter behind: the route is an
+    attribute of the eager kernel span."""
+    from repro.core.sparse_tensor import SparseTensor
+    from repro.kernels import ops as kops
+
+    st = SparseTensor.random(jax.random.PRNGKey(2), (20, 15, 10), 150)
+    fs = [jax.random.normal(jax.random.PRNGKey(30 + i), (d, 4))
+          for i, d in enumerate(st.shape)]
+    obs.enable()
+    kops.tttp_values(st, fs, use_pallas=False)
+    jax.jit(lambda s, f: kops.tttp_values(s, f))(st, fs)
+    assert obs.get_registry().summary()["counters"] == {}
+
+
+# ---------------------------------------------------------------------------
+# named scopes: every operation of a compiled ALS sweep names its mode, and
+# every gather and scatter its kernel family
+# ---------------------------------------------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
+# what XLA adds itself, or what runs nothing on its own
+_PLUMBING = ("parameter", "constant", "get-tuple-element", "tuple", "copy",
+             "bitcast")
+
+
+def _instructions(hlo_text):
+    """``(computation, name, opcode, op_name, elements)`` of every
+    instruction, and the computations called as fusions or reducers."""
+    out, called, comp = [], set(), None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if comp is None or not m:
+            continue
+        name, rest = m.groups()
+        called.update(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", rest))
+        opcode = re.search(r"(?:^|\s)([a-z][\w\-]*)\(", rest)
+        dims = re.match(r"\w+\[([\d,]*)\]", rest)
+        elements = 0 if dims is None else math.prod(
+            int(d) for d in dims.group(1).split(",") if d)
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        out.append((comp, name, opcode.group(1) if opcode else "",
+                    op_name.group(1) if op_name else None, elements))
+    return out, called
+
+
+def _compiled_sweep_text(nnz=2000):
+    from repro.core.completion import als_sweep
+    from repro.data import synthetic
+
+    key = jax.random.PRNGKey(0)
+    shape = (40, 30, 20)
+    st = synthetic.function_tensor(key, shape, nnz)
+    omega = st.with_values(jnp.ones_like(st.values))
+    fs = tuple(jax.random.normal(jax.random.fold_in(key, d), (n, 5))
+               for d, n in enumerate(shape))
+    fn = jax.jit(lambda s, o, f: tuple(als_sweep(s, o, list(f), 1e-4,
+                                                 cg_iters=20)))
+    return fn.lower(st, omega, fs).compile().as_text(), st.cap
+
+
+def test_compiled_sweep_operations_carry_their_scopes():
+    text, cap = _compiled_sweep_text()
+    insts, called = _instructions(text)
+    top = [i for i in insts if i[0] not in called
+           and i[2] not in ("while", "conditional", "call")]
+    scoped = [i for i in top if i[3] is not None and i[2] != "parameter"]
+    assert scoped
+    for comp, name, opcode, op_name, _ in scoped:
+        assert re.search(r"/mode_\d/", op_name), (name, op_name)
+    # what carries no op_name is XLA's own plumbing, or a fusion of it,
+    # and none of it runs over the nonzeros
+    for comp, name, opcode, op_name, elements in top:
+        if op_name is None:
+            assert opcode in _PLUMBING + ("fusion",), (name, opcode)
+            if opcode in ("copy", "fusion"):
+                assert elements < cap, (name, opcode, elements)
+    # every gather and scatter, fused or not, names its kernel family
+    moves = [i for i in insts if i[2] in ("gather", "scatter")]
+    assert {i[2] for i in moves} == {"gather", "scatter"}
+    for comp, name, opcode, op_name, _ in moves:
+        assert re.search(r"/(tttp|mttkrp)/", op_name or ""), (name, op_name)
+    # the solver's phases are named too
+    names = " ".join(i[3] for i in scoped)
+    for phase in ("rhs", "matvec", "cg_update"):
+        assert f"/{phase}/" in names, phase
 
 
 @pytest.mark.parametrize("family", ["mttkrp", "cg_matvec"])
