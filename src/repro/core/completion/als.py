@@ -151,6 +151,165 @@ def batched_cg(matvec, b: jax.Array, x0: jax.Array, tol: float = 1e-4,
                        max_iters=max_iters, ctx=ctx)
 
 
+# ---------------------------------------------------------------------------
+# The Gram operator of one mode in row-slab form
+#
+# Mode d's nonzeros, sorted by their mode-d row, are cut into slabs of SLAB
+# slots; a row of n nonzeros fills ceil(n / SLAB) slabs of its own. Each
+# slot holds its nonzero's Khatri-Rao row a_n = Π_{e≠d} A_e[i_e(n)] with the
+# slots on the lane axis and the rank on a major one, so no rank-R row is
+# ever padded to the lanes. Built once per mode update, the operator turns
+# each CG matvec into two streams over the slabs and a sorted scatter of one
+# row per slab, instead of gathers and a scatter over every nonzero.
+# ---------------------------------------------------------------------------
+
+SLAB = 128            # slots per slab: one lane row of a TPU vector register
+BUILD_SLOTS = 1 << 19  # slots whose factor rows one build step gathers
+
+
+class GramSlabs(NamedTuple):
+    """Mode ``d``'s Gram operator in row-slab form (:func:`gram_slabs`).
+    Slab ``c * P + s`` is ``[c, ..., s, :]``."""
+    z: jax.Array     # (C, R, P, SLAB) a_n
+    w: jax.Array     # (C, P, SLAB) ω_n, the Gram's weights; 0 if unused
+    v: jax.Array     # (C, P, SLAB) t_n, the right-hand side's; 0 if unused
+    row: jax.Array   # (C * P,) int32 row of each slab, sorted; I_d if unused
+
+
+def slab_mode(omega: SparseTensor, mode: int, ctx: AxisCtx = LOCAL,
+              h_slices: int = 1, mttkrp_path: Optional[str] = None) -> bool:
+    """Whether ALS solves ``mode`` on the row-slab operator: replicated
+    factor columns, no H-slicing, no planner path asked for, and at least
+    ``SLAB`` nonzeros a row on average (static: ``cap / I_d``). A mode of
+    shorter rows would spend most slots on padding; it keeps the COO
+    matvec."""
+    return (ctx.model is None and h_slices == 1 and mttkrp_path is None
+            and omega.cap >= SLAB * omega.shape[mode])
+
+
+def _row_counts(rows: jax.Array, n_rows: int,
+                chunk: int = 1 << 18) -> jax.Array:
+    """How many of ``rows`` fall on each of ``n_rows`` rows (others are
+    ignored): a histogram as int8 one-hot matmuls (row = 128 hi + lo), with
+    no scatter and no sort."""
+    hi_n = -(-n_rows // 128)
+    chunk = min(chunk, -(-rows.size // 128) * 128)
+    rows = jnp.pad(rows, (0, -rows.size % chunk),
+                   constant_values=-1).reshape(-1, chunk)
+
+    def step(acc, r):                                # one-hots (·, chunk)
+        lo = (r % 128 == jnp.arange(128)[:, None]).astype(jnp.int8)
+        hi = (r // 128 == jnp.arange(hi_n)[:, None]).astype(jnp.int8)
+        return acc + jax.lax.dot_general(
+            hi, lo, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros((hi_n, 128), jnp.int32), rows)
+    return acc.reshape(-1)[:n_rows]
+
+
+def gram_slabs(st: SparseTensor, omega: SparseTensor,
+               factors: Sequence[jax.Array], mode: int) -> GramSlabs:
+    """Build mode ``mode``'s :class:`GramSlabs` from ``omega``'s pattern,
+    which ``st`` shares (``omega`` is ``st.with_values(...)``, as ALS takes
+    them).
+
+    Each row ``i`` is given ``-n_i mod SLAB`` pad entries (out of ``SLAB -
+    1`` it holds in reserve, the rest going to the sentinel row ``I_d``
+    with the invalid entries), so that once nonzeros and pads are sorted by
+    row every row's run fills whole slabs and the sorted order *is* the
+    slab layout: ``cap + I_d (SLAB - 1)`` entries, so no shape depends on
+    the data. The sort key carries as many of the other coordinates in its
+    low bits as fit in 31 bits, and the sort carries the rest, the weights
+    and the values along: a gather from an array of ``cap`` entries in a
+    random order is far slower on a TPU than the sort, and each operand
+    less saves the TPU compiler close to a minute. The Khatri-Rao rows are
+    then gathered from the factors ``BUILD_SLOTS`` slots a step, so the
+    lane-padded rows never span all the slots at once, and a step past the
+    last row's slabs is skipped."""
+    n_rows, cap, k = omega.shape[mode], omega.cap, SLAB
+    others = [e for e in range(omega.ndim) if e != mode]
+    rank, dtype = factors[others[0]].shape[1], factors[others[0]].dtype
+    row = jnp.where(omega.valid, omega.indices[:, mode], n_rows)
+    pads = -_row_counts(row, n_rows) % k                            # (I_d,)
+    pad_row = jnp.where(jnp.arange(k - 1) < pads[:, None],
+                        jnp.arange(n_rows, dtype=row.dtype)[:, None], n_rows)
+    entries = cap + n_rows * (k - 1)
+    per = max(1, min(-(-entries // k), BUILD_SLOTS // k))
+    chunks = -(-entries // (per * k))
+    fill = chunks * per * k - cap
+
+    def padded(a, value=0):
+        return jnp.concatenate([a, jnp.full((fill,), value, a.dtype)])
+
+    rows = padded(row, n_rows).at[cap:cap + pad_row.size].set(
+        pad_row.reshape(-1))
+    # the row, then the narrowest other coordinates that fit, in one key
+    keys, packed = rows, []
+    for e in sorted(others, key=lambda e: omega.shape[e]):
+        b = max(1, (omega.shape[e] - 1).bit_length())
+        if n_rows.bit_length() + sum(n for _, n in packed) + b > 31:
+            break
+        keys = (keys << b) | padded(omega.indices[:, e])
+        packed.append((e, b))
+    rest = [e for e in others if e not in dict(packed)]
+    w = jnp.where(omega.valid, omega.values, 0)
+    v = jnp.where(st.valid, st.values, 0).astype(w.dtype)
+    keys, *rest_cols, w, v = jax.lax.sort(
+        (keys, *[padded(omega.indices[:, e]) for e in rest], padded(w),
+         padded(v)), num_keys=1)
+    cols = dict(zip(rest, rest_cols))
+    for e, b in reversed(packed):
+        cols[e], keys = keys & ((1 << b) - 1), keys >> b
+    shape = (chunks, per, k)
+    cols = jnp.stack([cols[e].reshape(-1, per * k) for e in others], 1)
+
+    def build(idx):                                 # (E, P*K) -> (R, P, K)
+        prod = None
+        for e, col in zip(others, idx):
+            got = factors[e][col]
+            prod = got if prod is None else prod * got
+        return prod.T.reshape(-1, per, k)
+
+    # a step past the last row's slabs holds only the sentinel row
+    z = jnp.stack([jax.lax.cond(keys[c * per * k] < n_rows, build,
+                                lambda idx: jnp.zeros((rank, per, k), dtype),
+                                cols[c]) for c in range(chunks)])
+    return GramSlabs(z, w.reshape(shape), v.reshape(shape),
+                     keys.reshape(-1, k)[:, 0])
+
+
+def _slab_rows(ops: GramSlabs, y: jax.Array, n_rows: int,
+               ctx: AxisCtx) -> jax.Array:
+    """Sum ``(C, R, P)`` per-slab rows into their rows: a sorted scatter of
+    one row per slab, then the psum over the data axes."""
+    y = y.transpose(0, 2, 1).reshape(ops.row.shape[0], -1)
+    return ctx.psum_data(jax.ops.segment_sum(
+        y, ops.row, num_segments=n_rows, indices_are_sorted=True))
+
+
+def slab_matvec(ops: GramSlabs, x: jax.Array, lam: float,
+                ctx: AxisCtx = LOCAL) -> jax.Array:
+    """``(G_ω + λI) x`` on the row-slab operator: what :func:`gram_matvec`
+    computes, with one gather of ``x`` per slab and no pass over the
+    nonzeros' coordinates."""
+    c, r, p, _ = ops.z.shape
+    with obs.scope("tttp"):
+        xs = jnp.take(x, ops.row, axis=0, mode="clip")
+        xs = xs.reshape(c, p, r).transpose(0, 2, 1)                 # (C, R, P)
+        t = ops.w * jnp.sum(ops.z * xs[..., None], axis=1)          # (C, P, K)
+    with obs.scope("mttkrp"):
+        y = _slab_rows(ops, jnp.sum(ops.z * t[:, None], axis=-1),
+                       x.shape[0], ctx)
+    return y + lam * x
+
+
+def slab_rhs(ops: GramSlabs, n_rows: int, ctx: AxisCtx = LOCAL) -> jax.Array:
+    """The right-hand side ``MTTKRP(st)`` on the row-slab operator."""
+    return _slab_rows(ops, jnp.sum(ops.z * ops.v[:, None], axis=-1),
+                      n_rows, ctx)
+
+
 def als_update_mode(st: SparseTensor, omega: SparseTensor,
                     factors: List[jax.Array], mode: int, lam: float,
                     cg_tol: float = 1e-4, cg_iters: int = 32,
@@ -159,14 +318,32 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
     """One ALS factor update by implicit CG; returns ``(factor, CG
     steps)``. The right-hand-side MTTKRP runs under ``obs.scope("rhs")``.
     ``mttkrp_path`` opts the MTTKRP contractions into planner dispatch
-    (repro.planner)."""
-    fs = list(factors)
-    fs[mode] = None
-    with obs.scope("rhs"):
-        b = mttkrp_ctx(st, fs, mode, ctx, path=mttkrp_path)
-    mv = functools.partial(gram_matvec, omega, factors, mode, lam=lam,
-                           ctx=ctx, h_slices=h_slices,
-                           mttkrp_path=mttkrp_path)
+    (repro.planner).
+
+    Where :func:`slab_mode` holds, the mode's Gram operator is built once,
+    under ``obs.scope("gram_build")``, in row-slab form (:func:`gram_slabs`)
+    and both the right-hand side and every CG matvec apply it; otherwise
+    the COO :func:`gram_matvec` runs. Each traced update bumps the counter
+    ``als/gram/slab`` or ``als/gram/coo``; a slab update also sets the
+    gauges ``als/gram/mode_<d>/slots`` and ``.../cap``."""
+    if slab_mode(omega, mode, ctx, h_slices, mttkrp_path):
+        obs.counter_add("als/gram/slab")
+        with obs.scope("gram_build"):
+            ops = gram_slabs(st, omega, factors, mode)
+        obs.gauge_set(f"als/gram/mode_{mode}/slots", ops.w.size)
+        obs.gauge_set(f"als/gram/mode_{mode}/cap", omega.cap)
+        with obs.scope("rhs"):
+            b = slab_rhs(ops, omega.shape[mode], ctx)
+        mv = functools.partial(slab_matvec, ops, lam=lam, ctx=ctx)
+    else:
+        obs.counter_add("als/gram/coo")
+        fs = list(factors)
+        fs[mode] = None
+        with obs.scope("rhs"):
+            b = mttkrp_ctx(st, fs, mode, ctx, path=mttkrp_path)
+        mv = functools.partial(gram_matvec, omega, factors, mode, lam=lam,
+                               ctx=ctx, h_slices=h_slices,
+                               mttkrp_path=mttkrp_path)
     return batched_cg(mv, b, factors[mode], tol=cg_tol, max_iters=cg_iters,
                       ctx=ctx)
 

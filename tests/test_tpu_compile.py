@@ -2,7 +2,9 @@
 
 (a) The jitted ALS and GGN sweeps compile for one chip at the deployment
     ``chip_smoke.py`` runs (Netflix extents, rank 32, its cut nnz) and fit
-    the chip's 16 GiB with 10% headroom, by ``memory_analysis()``.
+    the chip's 16 GiB with 10% headroom, by ``memory_analysis()``. At the
+    benchmark's function-10b extents every ALS mode takes the row-slab Gram
+    operator and needs no more device bytes than the COO matvec.
 (b) Each Pallas kernel family is compiled once for the chip at a geometry
     that fits VMEM. Mosaic refuses all three today (``kernels.ops.
     TPU_REFUSED``), so these are strict xfails: a change that makes a kernel
@@ -14,6 +16,7 @@ process at a time may load the TPU library, so nothing here may touch it
 while modules are imported or tests collected.
 """
 import importlib.util
+import json
 import os
 
 import jax
@@ -21,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core import losses as LOSS
 from repro.core.completion import als_sweep, ggn_sweep
 from repro.core.completion.gauss_newton import GGNState
@@ -72,19 +76,40 @@ def _device_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
+def _als_compile(sharding, shape, m, r, lam, **kw):
+    """The jitted ALS sweep compiled for ``sharding``'s chip, and the
+    ``als/gram/*`` counters its trace bumped."""
+    st = SparseTensor(_sds(sharding, (m, len(shape)), jnp.int32),
+                      _sds(sharding, (m,), jnp.float32),
+                      _sds(sharding, (m,), jnp.bool_), tuple(shape), m)
+    fs = tuple(_sds(sharding, (d, r), jnp.float32) for d in shape)
+    fn = jax.jit(lambda s, o, f: tuple(als_sweep(
+        s, o, list(f), lam, cg_tol=1e-4, cg_iters=20, **kw)))
+    obs.get_registry().reset()
+    obs.enable()
+    try:
+        compiled = fn.lower(st, st, fs).compile()
+        counters = obs.get_registry().summary()["counters"]
+    finally:
+        obs.disable()
+        obs.get_registry().reset()
+    return compiled, {k: v for k, v in counters.items()
+                      if k.startswith("als/gram/")}
+
+
 @pytest.mark.parametrize("solver", ["als", "ggn"])
 def test_sweep_fits_one_chip(one_chip, solver):
     cs = _chip_smoke()
     m, shape, r = cs.NNZ, cs.NETFLIX_SHAPE, cs.RANK
-    st = SparseTensor(_sds(one_chip, (m, 3), jnp.int32),
-                      _sds(one_chip, (m,), jnp.float32),
-                      _sds(one_chip, (m,), jnp.bool_), shape, m)
-    fs = tuple(_sds(one_chip, (d, r), jnp.float32) for d in shape)
     if solver == "als":
-        fn = jax.jit(lambda s, o, f: tuple(als_sweep(
-            s, o, list(f), cs.LAM, cg_tol=1e-4, cg_iters=20)))
-        compiled = fn.lower(st, st, fs).compile()
+        compiled, counters = _als_compile(one_chip, shape, m, r, cs.LAM)
+        # the user mode's rows are short (about 6.5 nonzeros): COO there
+        assert counters == {"als/gram/coo": 1.0, "als/gram/slab": 2.0}
     else:
+        st = SparseTensor(_sds(one_chip, (m, 3), jnp.int32),
+                          _sds(one_chip, (m,), jnp.float32),
+                          _sds(one_chip, (m,), jnp.bool_), shape, m)
+        fs = tuple(_sds(one_chip, (d, r), jnp.float32) for d in shape)
         loss = LOSS.LOSSES["poisson_log"]
         fn = jax.jit(lambda s, state: ggn_sweep(
             s, state, loss, cs.LAM, cg_tol=1e-4, cg_iters=20))
@@ -93,6 +118,23 @@ def test_sweep_fits_one_chip(one_chip, solver):
     used = _device_bytes(compiled)
     assert used <= HEADROOM * HBM_BYTES, (
         f"{solver} sweep at nnz={m} needs {used / 2 ** 30:.2f} GiB")
+
+
+def test_als_slab_path_at_function_extents(one_chip):
+    """At the function-10b cell's extents (16,384 a mode, rank 10,
+    3,139,928 nonzeros) all three modes take the row-slab operator, and
+    the sweep needs no more device bytes than the COO matvec, which an
+    explicit ``mttkrp_path`` keeps."""
+    with open(os.path.join(_ROOT, "chipbench", "configs",
+                           "function-10b.json")) as f:
+        cfg = json.load(f)
+    args = (cfg["shape"], cfg["nnz_per_chip"], cfg["rank"], cfg["lam"])
+    slab, counters = _als_compile(one_chip, *args)
+    assert counters == {"als/gram/slab": 3.0}
+    coo, counters = _als_compile(one_chip, *args, mttkrp_path="all_at_once")
+    assert counters == {"als/gram/coo": 3.0}
+    assert _device_bytes(slab) <= _device_bytes(coo), (
+        _device_bytes(slab) / 2 ** 30, _device_bytes(coo) / 2 ** 30)
 
 
 def _kernel_case(family, sharding):
