@@ -325,7 +325,10 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
     and both the right-hand side and every CG matvec apply it; otherwise
     the COO :func:`gram_matvec` runs. Each traced update bumps the counter
     ``als/gram/slab`` or ``als/gram/coo``; a slab update also sets the
-    gauges ``als/gram/mode_<d>/slots`` and ``.../cap``."""
+    gauges ``als/gram/mode_<d>/slots`` and ``.../cap``, a COO update
+    ``als/gram/mode_<d>/rows``, ``.../cap`` and ``.../lanes`` (the lanes
+    each gathered or scattered rank-R row occupies, R rounded up to a
+    whole number of ``SLAB``-lane rows)."""
     if slab_mode(omega, mode, ctx, h_slices, mttkrp_path):
         obs.counter_add("als/gram/slab")
         with obs.scope("gram_build"):
@@ -337,6 +340,10 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
         mv = functools.partial(slab_matvec, ops, lam=lam, ctx=ctx)
     else:
         obs.counter_add("als/gram/coo")
+        obs.gauge_set(f"als/gram/mode_{mode}/rows", omega.shape[mode])
+        obs.gauge_set(f"als/gram/mode_{mode}/cap", omega.cap)
+        obs.gauge_set(f"als/gram/mode_{mode}/lanes",
+                      -(-factors[mode].shape[1] // SLAB) * SLAB)
         fs = list(factors)
         fs[mode] = None
         with obs.scope("rhs"):

@@ -1,7 +1,8 @@
 """Compile-only checks against a described TPU v5e (no chip needed).
 
-(a) The jitted ALS and GGN sweeps compile for one chip at the deployment
-    ``chip_smoke.py`` runs (Netflix extents, rank 32, its cut nnz) and fit
+(a) The jitted ALS and GGN sweeps compile for one chip at the benchmark's
+    ``netflix`` deployment (``chipbench/configs/netflix.json``: Netflix
+    extents, rank 32, one chip's 1/32 of the ratings) and fit
     the chip's 16 GiB with 10% headroom, by ``memory_analysis()``. At the
     benchmark's function-10b extents every ALS mode takes the row-slab Gram
     operator and needs no more device bytes than the COO matvec.
@@ -15,7 +16,6 @@ The topology is described inside a module-scoped fixture only: only one
 process at a time may load the TPU library, so nothing here may touch it
 while modules are imported or tests collected.
 """
-import importlib.util
 import json
 import os
 
@@ -41,12 +41,11 @@ HBM_BYTES = 16 * 2 ** 30             # one v5e chip
 HEADROOM = 0.9
 
 
-def _chip_smoke():
-    spec = importlib.util.spec_from_file_location(
-        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _config(name):
+    """A benchmark configuration (``chipbench/configs/<name>.json``)."""
+    with open(os.path.join(_ROOT, "chipbench", "configs",
+                           f"{name}.json")) as f:
+        return json.load(f)
 
 
 @pytest.fixture(scope="module")
@@ -99,10 +98,11 @@ def _als_compile(sharding, shape, m, r, lam, **kw):
 
 @pytest.mark.parametrize("solver", ["als", "ggn"])
 def test_sweep_fits_one_chip(one_chip, solver):
-    cs = _chip_smoke()
-    m, shape, r = cs.NNZ, cs.NETFLIX_SHAPE, cs.RANK
+    cfg = _config("netflix")
+    m, shape, r, lam = (cfg["nnz_per_chip"], tuple(cfg["shape"]), cfg["rank"],
+                        cfg["lam"])
     if solver == "als":
-        compiled, counters = _als_compile(one_chip, shape, m, r, cs.LAM)
+        compiled, counters = _als_compile(one_chip, shape, m, r, lam)
         # the user mode's rows are short (about 6.5 nonzeros): COO there
         assert counters == {"als/gram/coo": 1.0, "als/gram/slab": 2.0}
     else:
@@ -112,7 +112,7 @@ def test_sweep_fits_one_chip(one_chip, solver):
         fs = tuple(_sds(one_chip, (d, r), jnp.float32) for d in shape)
         loss = LOSS.LOSSES["poisson_log"]
         fn = jax.jit(lambda s, state: ggn_sweep(
-            s, state, loss, cs.LAM, cg_tol=1e-4, cg_iters=20))
+            s, state, loss, lam, cg_tol=1e-4, cg_iters=20))
         compiled = fn.lower(st, GGNState(fs, _sds(one_chip, (),
                                                   jnp.float32))).compile()
     used = _device_bytes(compiled)
@@ -125,9 +125,7 @@ def test_als_slab_path_at_function_extents(one_chip):
     3,139,928 nonzeros) all three modes take the row-slab operator, and
     the sweep needs no more device bytes than the COO matvec, which an
     explicit ``mttkrp_path`` keeps."""
-    with open(os.path.join(_ROOT, "chipbench", "configs",
-                           "function-10b.json")) as f:
-        cfg = json.load(f)
+    cfg = _config("function-10b")
     args = (cfg["shape"], cfg["nnz_per_chip"], cfg["rank"], cfg["lam"])
     slab, counters = _als_compile(one_chip, *args)
     assert counters == {"als/gram/slab": 3.0}
