@@ -154,37 +154,62 @@ def batched_cg(matvec, b: jax.Array, x0: jax.Array, tol: float = 1e-4,
 # ---------------------------------------------------------------------------
 # The Gram operator of one mode in row-slab form
 #
-# Mode d's nonzeros, sorted by their mode-d row, are cut into slabs of SLAB
-# slots; a row of n nonzeros fills ceil(n / SLAB) slabs of its own. Each
-# slot holds its nonzero's Khatri-Rao row a_n = Π_{e≠d} A_e[i_e(n)] with the
-# slots on the lane axis and the rank on a major one, so no rank-R row is
-# ever padded to the lanes. Built once per mode update, the operator turns
-# each CG matvec into two streams over the slabs and a sorted scatter of one
-# row per slab, instead of gathers and a scatter over every nonzero.
+# Mode d's nonzeros, sorted by their mode-d row, are cut into slabs of K
+# slots; a row of n nonzeros fills ceil(n / K) slabs of its own. Each slot
+# holds its nonzero's Khatri-Rao row a_n = Π_{e≠d} A_e[i_e(n)], with the rank
+# on a major axis, so no rank-R row is ever padded to the lanes. K is read
+# from the mode's static shape (slab_width): SLAB where rows are long, the
+# slots then on the lane axis; a narrower power of two where they are short,
+# the slabs then on the lanes and a slab's K slots on the sublanes. Built
+# once per mode update, the operator turns each CG matvec into two streams
+# over the slabs and a sorted scatter of one row per slab, instead of
+# gathers and a scatter over every nonzero.
 # ---------------------------------------------------------------------------
 
 SLAB = 128            # slots per slab: one lane row of a TPU vector register
+NARROW = 8            # the narrowest slab: one float32 sublane tile
 BUILD_SLOTS = 1 << 19  # slots whose factor rows one build step gathers
 
 
 class GramSlabs(NamedTuple):
-    """Mode ``d``'s Gram operator in row-slab form (:func:`gram_slabs`).
-    Slab ``c * P + s`` is ``[c, ..., s, :]``."""
-    z: jax.Array     # (C, R, P, SLAB) a_n
-    w: jax.Array     # (C, P, SLAB) ω_n, the Gram's weights; 0 if unused
-    v: jax.Array     # (C, P, SLAB) t_n, the right-hand side's; 0 if unused
+    """Mode ``d``'s Gram operator in row-slab form (:func:`gram_slabs`),
+    its slabs of ``K`` slots laid out by width: at ``K == SLAB`` slab
+    ``c * P + s`` is ``[c, ..., s, :]``, narrower ``[c, ..., :, s]``."""
+    z: jax.Array     # (C, R, P, K) or (C, R, K, P) a_n
+    w: jax.Array     # (C, P, K) or (C, K, P) ω_n, the Gram's; 0 if unused
+    v: jax.Array     # as w: t_n, the right-hand side's; 0 if unused
     row: jax.Array   # (C * P,) int32 row of each slab, sorted; I_d if unused
+
+    @property
+    def width(self) -> int:
+        """``K``, the slots of one slab."""
+        return self.w.size // self.row.size
+
+    @property
+    def slot_axis(self) -> int:
+        """The axis of ``z`` that holds a slab's slots."""
+        return -1 if self.width == SLAB else -2
+
+
+def slab_width(omega: SparseTensor, mode: int) -> int:
+    """Slots per slab for ``mode``: ``SLAB`` where its rows hold at least
+    that many nonzeros on average (static: ``cap / I_d``), else the least
+    power of two, from ``NARROW``, that is at least that average."""
+    k, n_rows = NARROW, omega.shape[mode]
+    while k < SLAB and k * n_rows < omega.cap:
+        k *= 2
+    return k
 
 
 def slab_mode(omega: SparseTensor, mode: int, ctx: AxisCtx = LOCAL,
               h_slices: int = 1, mttkrp_path: Optional[str] = None) -> bool:
     """Whether ALS solves ``mode`` on the row-slab operator: replicated
     factor columns, no H-slicing, no planner path asked for, and at least
-    ``SLAB`` nonzeros a row on average (static: ``cap / I_d``). A mode of
-    shorter rows would spend most slots on padding; it keeps the COO
-    matvec."""
+    one nonzero a row on average (static: ``cap >= I_d``). A sparser mode
+    would spend most slots on padding (a reserve of ``K - 1`` a row); it
+    keeps the COO matvec."""
     return (ctx.model is None and h_slices == 1 and mttkrp_path is None
-            and omega.cap >= SLAB * omega.shape[mode])
+            and omega.cap >= omega.shape[mode])
 
 
 def _row_counts(rows: jax.Array, n_rows: int,
@@ -214,20 +239,22 @@ def gram_slabs(st: SparseTensor, omega: SparseTensor,
     which ``st`` shares (``omega`` is ``st.with_values(...)``, as ALS takes
     them).
 
-    Each row ``i`` is given ``-n_i mod SLAB`` pad entries (out of ``SLAB -
-    1`` it holds in reserve, the rest going to the sentinel row ``I_d``
-    with the invalid entries), so that once nonzeros and pads are sorted by
-    row every row's run fills whole slabs and the sorted order *is* the
-    slab layout: ``cap + I_d (SLAB - 1)`` entries, so no shape depends on
-    the data. The sort key carries as many of the other coordinates in its
-    low bits as fit in 31 bits, and the sort carries the rest, the weights
-    and the values along: a gather from an array of ``cap`` entries in a
-    random order is far slower on a TPU than the sort, and each operand
-    less saves the TPU compiler close to a minute. The Khatri-Rao rows are
-    then gathered from the factors ``BUILD_SLOTS`` slots a step, so the
-    lane-padded rows never span all the slots at once, and a step past the
-    last row's slabs is skipped."""
-    n_rows, cap, k = omega.shape[mode], omega.cap, SLAB
+    Slabs are ``K = slab_width(omega, mode)`` slots wide. Each row ``i`` is
+    given ``-n_i mod K`` pad entries (out of ``K - 1`` it holds in reserve,
+    the rest going to the sentinel row ``I_d`` with the invalid entries),
+    so that once nonzeros and pads are sorted by row every row's run fills
+    whole slabs and the sorted order *is* the slab order: ``cap + I_d (K -
+    1)`` entries, so no shape depends on the data. The sort key carries as
+    many of the other coordinates in its low bits as fit in 31 bits, and
+    the sort carries the rest, the weights and the values along: a gather
+    from an array of ``cap`` entries in a random order is far slower on a
+    TPU than the sort, and each operand less saves the TPU compiler close
+    to a minute. The Khatri-Rao rows are then gathered from the factors
+    ``BUILD_SLOTS`` slots a step, so the lane-padded rows never span all
+    the slots at once, and a step past the last row's slabs is skipped.
+    Below ``SLAB`` each step's slots are taken slot-major, so that its
+    slabs land on the lanes."""
+    n_rows, cap, k = omega.shape[mode], omega.cap, slab_width(omega, mode)
     others = [e for e in range(omega.ndim) if e != mode]
     rank, dtype = factors[others[0]].shape[1], factors[others[0]].dtype
     row = jnp.where(omega.valid, omega.indices[:, mode], n_rows)
@@ -261,22 +288,27 @@ def gram_slabs(st: SparseTensor, omega: SparseTensor,
     cols = dict(zip(rest, rest_cols))
     for e, b in reversed(packed):
         cols[e], keys = keys & ((1 << b) - 1), keys >> b
-    shape = (chunks, per, k)
-    cols = jnp.stack([cols[e].reshape(-1, per * k) for e in others], 1)
 
-    def build(idx):                                 # (E, P*K) -> (R, P, K)
+    step = (per, k) if k == SLAB else (k, per)
+
+    def laid(a):                               # (chunks * P * K,) -> layout
+        a = a.reshape(chunks, per, k)
+        return a if k == SLAB else a.transpose(0, 2, 1)
+
+    cols = jnp.stack([laid(cols[e]).reshape(chunks, -1) for e in others], 1)
+
+    def build(idx):                             # (E, P*K) -> (R, *step)
         prod = None
         for e, col in zip(others, idx):
             got = factors[e][col]
             prod = got if prod is None else prod * got
-        return prod.T.reshape(-1, per, k)
+        return prod.T.reshape(-1, *step)
 
     # a step past the last row's slabs holds only the sentinel row
     z = jnp.stack([jax.lax.cond(keys[c * per * k] < n_rows, build,
-                                lambda idx: jnp.zeros((rank, per, k), dtype),
+                                lambda idx: jnp.zeros((rank, *step), dtype),
                                 cols[c]) for c in range(chunks)])
-    return GramSlabs(z, w.reshape(shape), v.reshape(shape),
-                     keys.reshape(-1, k)[:, 0])
+    return GramSlabs(z, laid(w), laid(v), keys.reshape(-1, k)[:, 0])
 
 
 def _slab_rows(ops: GramSlabs, y: jax.Array, n_rows: int,
@@ -293,21 +325,22 @@ def slab_matvec(ops: GramSlabs, x: jax.Array, lam: float,
     """``(G_ω + λI) x`` on the row-slab operator: what :func:`gram_matvec`
     computes, with one gather of ``x`` per slab and no pass over the
     nonzeros' coordinates."""
-    c, r, p, _ = ops.z.shape
+    c, r = ops.z.shape[:2]
     with obs.scope("tttp"):
         xs = jnp.take(x, ops.row, axis=0, mode="clip")
-        xs = xs.reshape(c, p, r).transpose(0, 2, 1)                 # (C, R, P)
-        t = ops.w * jnp.sum(ops.z * xs[..., None], axis=1)          # (C, P, K)
+        xs = xs.reshape(c, -1, r).transpose(0, 2, 1)                # (C, R, P)
+        t = ops.w * jnp.sum(ops.z * jnp.expand_dims(xs, ops.slot_axis),
+                            axis=1)                                 # as ops.w
     with obs.scope("mttkrp"):
-        y = _slab_rows(ops, jnp.sum(ops.z * t[:, None], axis=-1),
+        y = _slab_rows(ops, jnp.sum(ops.z * t[:, None], axis=ops.slot_axis),
                        x.shape[0], ctx)
     return y + lam * x
 
 
 def slab_rhs(ops: GramSlabs, n_rows: int, ctx: AxisCtx = LOCAL) -> jax.Array:
     """The right-hand side ``MTTKRP(st)`` on the row-slab operator."""
-    return _slab_rows(ops, jnp.sum(ops.z * ops.v[:, None], axis=-1),
-                      n_rows, ctx)
+    return _slab_rows(ops, jnp.sum(ops.z * ops.v[:, None],
+                                   axis=ops.slot_axis), n_rows, ctx)
 
 
 def als_update_mode(st: SparseTensor, omega: SparseTensor,
@@ -325,7 +358,8 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
     and both the right-hand side and every CG matvec apply it; otherwise
     the COO :func:`gram_matvec` runs. Each traced update bumps the counter
     ``als/gram/slab`` or ``als/gram/coo``; a slab update also sets the
-    gauges ``als/gram/mode_<d>/slots`` and ``.../cap``, a COO update
+    gauges ``als/gram/mode_<d>/slots``, ``.../cap`` and ``.../width``
+    (``K``, the slots of one slab), a COO update
     ``als/gram/mode_<d>/rows``, ``.../cap`` and ``.../lanes`` (the lanes
     each gathered or scattered rank-R row occupies, R rounded up to a
     whole number of ``SLAB``-lane rows)."""
@@ -335,6 +369,7 @@ def als_update_mode(st: SparseTensor, omega: SparseTensor,
             ops = gram_slabs(st, omega, factors, mode)
         obs.gauge_set(f"als/gram/mode_{mode}/slots", ops.w.size)
         obs.gauge_set(f"als/gram/mode_{mode}/cap", omega.cap)
+        obs.gauge_set(f"als/gram/mode_{mode}/width", ops.width)
         with obs.scope("rhs"):
             b = slab_rhs(ops, omega.shape[mode], ctx)
         mv = functools.partial(slab_matvec, ops, lam=lam, ctx=ctx)

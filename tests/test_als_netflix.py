@@ -72,12 +72,14 @@ def _sweep():
 
 
 def test_user_mode_coo_and_long_modes_slab():
-    """The shape rule puts the short user rows on the COO matvec and the
-    movie and day rows on the slab operator; each path's gauges say how
-    the mode was laid out, and tracing with ``obs`` off records none."""
+    """The shape rule puts every mode on the slab operator: the short user
+    rows on slabs of 8 slots, the movie and day rows on slabs of 128; the
+    gauges say how each mode was laid out, and tracing with ``obs`` off
+    records none. (The name is the one this test had when the user mode
+    kept the COO matvec.)"""
     _, _, st, omega, fs = _problem(3)
-    assert [als.slab_mode(omega, d) for d in range(3)] == [False, True,
-                                                           True]
+    assert all(als.slab_mode(omega, d) for d in range(3))
+    assert [als.slab_width(omega, d) for d in range(3)] == [8, 128, 128]
     obs.get_registry().reset()
     obs.enable()
     try:
@@ -88,13 +90,12 @@ def test_user_mode_coo_and_long_modes_slab():
         obs.get_registry().reset()
     counters = {k: v for k, v in summary["counters"].items()
                 if k.startswith("als/gram/")}
-    assert counters == {"als/gram/coo": 1.0, "als/gram/slab": 2.0}
+    assert counters == {"als/gram/slab": 3.0}
     g = summary["gauges"]
-    assert g["als/gram/mode_0/rows"] == SHAPE[0]
     assert g["als/gram/mode_0/cap"] == st.cap
-    assert g["als/gram/mode_0/lanes"] == 128        # rank 32 in one lane row
-    assert "als/gram/mode_0/slots" not in g
-    for d in (1, 2):
+    assert g["als/gram/mode_0/slots"] >= st.cap + SHAPE[0] * (8 - 1)
+    for d, width in enumerate((8, 128, 128)):
+        assert g[f"als/gram/mode_{d}/width"] == width
         assert g[f"als/gram/mode_{d}/slots"] >= st.cap
         assert f"als/gram/mode_{d}/lanes" not in g
     _sweep().lower(st, omega, fs)

@@ -48,25 +48,44 @@ def _factors(key, shape, r):
             for d, s in enumerate(shape)]
 
 
-# mode-0 rows: empty, singletons, one exact slab, one over three slabs
+# mode-0 rows of K = 128 slots: empty, singletons, one exact slab, one
+# over three slabs
 _COUNTS = [0, 1, 5, 0, 128, 1, 417, 60, 0, 129, 1, 3]
+# mode-0 rows of K = 8 (7.3 entries a row with the 37 invalid ones): empty,
+# singletons, K - 1, K, K + 1, three and four slabs
+_NARROW_COUNTS = [0, 1, 7, 8, 9, 0, 1, 26, 3, 0, 2, 17, 0, 1, 5, 0]
+# (mode-0 counts, their slab width, slots a build step) of each layout
+_WIDTHS = {"wide": (_COUNTS, 128, 4 * 128), "narrow": (_NARROW_COUNTS, 8, 64)}
 
 
+def _slots(ops):
+    """``ops.w`` as one row of ``K`` slots per slab, in slab order."""
+    w = np.asarray(ops.w)
+    if ops.slot_axis == -2:
+        w = w.swapaxes(-1, -2)
+    return w.reshape(ops.row.size, ops.width)
+
+
+@pytest.mark.parametrize("layout", sorted(_WIDTHS))
 @pytest.mark.parametrize("order,rank", [(3, 10), (3, 32), (4, 10), (4, 32)])
-def test_slab_operator_matches_coo(order, rank, monkeypatch):
+def test_slab_operator_matches_coo(order, rank, layout, monkeypatch):
     """Matvec and right-hand side on the slab operator equal the COO
     ``gram_matvec`` and ``mttkrp`` to float32 rounding, for every mode,
-    with weights other than one, and built over several build steps."""
-    monkeypatch.setattr(als, "BUILD_SLOTS", 4 * als.SLAB)
+    with weights other than one, and built over several build steps, at
+    128 slots a slab and at narrower widths (8 to 32, one per mode)."""
+    counts, width, build_slots = _WIDTHS[layout]
+    monkeypatch.setattr(als, "BUILD_SLOTS", build_slots)
     key = jax.random.PRNGKey(order * 100 + rank)
-    shape = (len(_COUNTS), 9, 7, 5)[:order]
-    st = _rows_tensor(key, shape, _COUNTS)
+    shape = (len(counts), 9, 7, 5)[:order]
+    st = _rows_tensor(key, shape, counts)
     omega = st.with_values(
         jax.random.uniform(jax.random.fold_in(key, 7), (st.cap,),
                            minval=0.5, maxval=2.0))
     fs = _factors(key, shape, rank)
+    assert als.slab_width(omega, 0) == width
     for mode in range(order):
         ops = als.gram_slabs(st, omega, fs, mode)
+        assert ops.width == als.slab_width(omega, mode)
         assert ops.z.shape[0] > 1 and ops.z.shape[1] == rank
         assert np.all(np.diff(np.asarray(ops.row)) >= 0)
         x = jax.random.normal(jax.random.fold_in(key, 50 + mode),
@@ -83,38 +102,45 @@ def test_slab_operator_matches_coo(order, rank, monkeypatch):
                                    atol=1e-5 * scale)
 
 
-def test_slab_rows_hold_one_row_each():
+@pytest.mark.parametrize("layout", sorted(_WIDTHS))
+def test_slab_rows_hold_one_row_each(layout):
     """Every slab's used slots belong to the slab's row, and each row
     holds exactly its nonzeros' weights: the layout loses and invents
     nothing."""
+    counts, width, _ = _WIDTHS[layout]
     key = jax.random.PRNGKey(3)
-    shape = (len(_COUNTS), 6, 4)
-    st = _rows_tensor(key, shape, _COUNTS)
+    shape = (len(counts), 6, 4)
+    st = _rows_tensor(key, shape, counts)
     omega = st.with_values(jnp.ones_like(st.values))
     ops = als.gram_slabs(st, omega, _factors(key, shape, 4), 0)
+    assert ops.width == width
     row = np.asarray(ops.row)
-    w = np.asarray(ops.w).reshape(row.size, als.SLAB)
-    per_row = np.zeros(len(_COUNTS) + 1)
+    w = _slots(ops)
+    per_row = np.zeros(len(counts) + 1)
     np.add.at(per_row, row, w.sum(1))
-    np.testing.assert_array_equal(per_row[:-1], _COUNTS)
+    np.testing.assert_array_equal(per_row[:-1], counts)
     assert per_row[-1] == 0            # the sentinel row holds no weight
     used = (w != 0).sum(1)
-    want_slabs = sum(-(-c // als.SLAB) for c in _COUNTS)
-    assert (row < len(_COUNTS)).sum() == want_slabs
-    assert used[row == len(_COUNTS)].sum() == 0
+    want_slabs = sum(-(-c // width) for c in counts)
+    assert (row < len(counts)).sum() == want_slabs
+    assert used[row == len(counts)].sum() == 0
 
 
 def test_shape_rule_and_counters():
-    """A mode with fewer than SLAB nonzeros a row on average keeps the COO
-    matvec; the ``als/gram/*`` counters say which path each mode took, and
-    any context, H-slicing or planner path the slab operator does not
-    serve keeps every mode on COO."""
+    """A mode with fewer than SLAB nonzeros a row on average takes slabs
+    of the least power of two from 8 that holds its average, and one with
+    fewer nonzeros than rows keeps the COO matvec; the ``als/gram/*``
+    counters say which path each mode took, the ``width`` gauge at what
+    width, and any context, H-slicing or planner path the slab operator
+    does not serve keeps every mode on COO."""
     key = jax.random.PRNGKey(4)
-    shape = (8, 200, 6)                        # 250, 10, 333 a row
+    shape = (8, 200, 6, 4000)                  # 250, 10, 333, 0.5 a row
     st = SparseTensor.random(key, shape, 2000)
     omega = st.with_values(jnp.ones_like(st.values))
     fs = tuple(_factors(key, shape, 4))
-    assert [als.slab_mode(omega, d) for d in range(3)] == [True, False, True]
+    assert [als.slab_mode(omega, d) for d in range(4)] == [True, True, True,
+                                                           False]
+    assert [als.slab_width(omega, d) for d in range(3)] == [128, 16, 128]
     assert not als.slab_mode(omega, 0, ctx=AxisCtx(model="model"))
     assert not als.slab_mode(omega, 0, h_slices=2)
     assert not als.slab_mode(omega, 0, mttkrp_path="all_at_once")
@@ -133,20 +159,29 @@ def test_shape_rule_and_counters():
                  if k.startswith("als/gram/")}, summary["gauges"])
 
     got, gauges = counters()
-    assert got == {"als/gram/slab": 2.0, "als/gram/coo": 1.0}
+    assert got == {"als/gram/slab": 3.0, "als/gram/coo": 1.0}
     assert gauges["als/gram/mode_0/cap"] == st.cap
     assert gauges["als/gram/mode_0/slots"] >= st.cap + 8 * (als.SLAB - 1)
-    assert "als/gram/mode_1/slots" not in gauges
+    assert gauges["als/gram/mode_1/slots"] >= st.cap + 200 * (16 - 1)
+    assert [gauges[f"als/gram/mode_{d}/width"] for d in range(3)] == [
+        128, 16, 128]
+    assert gauges["als/gram/mode_3/rows"] == 4000
+    assert "als/gram/mode_3/slots" not in gauges
+    assert "als/gram/mode_3/width" not in gauges
     for kw in ({"h_slices": 2}, {"mttkrp_path": "all_at_once"}):
-        assert counters(**kw)[0] == {"als/gram/coo": 3.0}
+        assert counters(**kw)[0] == {"als/gram/coo": 4.0}
 
 
-def test_cg_steps_match_coo_path():
+@pytest.mark.parametrize("shape,widths", [
+    ((12, 10, 8), [128, 128, 128]),            # 250 to 375 a row
+    ((300, 250, 8), [16, 16, 128]),            # 10, 12 and 375 a row
+])
+def test_cg_steps_match_coo_path(shape, widths):
     """``als_sweep_stats`` on the slab path runs within one CG step per
     mode of the COO path (kept by an explicit ``mttkrp_path``) from the
-    same factors, and lands on the same factors to CG's tolerance."""
+    same factors, and lands on the same factors to CG's tolerance, at 128
+    slots a slab and at narrower widths."""
     key = jax.random.PRNGKey(6)
-    shape = (12, 10, 8)
     st = SparseTensor.random(key, shape, 3000)
     true = _factors(jax.random.PRNGKey(60), shape, 3)
     st = st.with_values(jnp.sum(true[0][st.indices[:, 0]]
@@ -155,6 +190,7 @@ def test_cg_steps_match_coo_path():
     omega = st.with_values(jnp.ones_like(st.values))
     fs = tuple(_factors(key, shape, 6))
     assert all(als.slab_mode(omega, d) for d in range(3))
+    assert [als.slab_width(omega, d) for d in range(3)] == widths
 
     def run(path):
         f = jax.jit(lambda s, o, f: als_sweep_stats(
@@ -187,56 +223,72 @@ _SHARDED = textwrap.dedent("""
     mesh = make_mesh((4,), ("data",))
     ctx = AxisCtx(data="data")
     key = jax.random.PRNGKey(0)
-    shape, r = (12, 10, 8), 5
-    st = SparseTensor.random(key, shape, 8000, cap=8192)
-    st = shuffle_and_pad(st, key, 4)
-    omega = st.with_values(jnp.ones_like(st.values))
-    fs = [jax.random.normal(jax.random.fold_in(key, d), (s, r)) / r ** 0.5
-          for d, s in enumerate(shape)]
-    spec = SparseTensor(P("data", None), P("data"), P("data"), st.shape,
-                        st.nnz, None)
+    r = 5
     rep = P(None, None)
+
+    def problem(shape):
+        st = SparseTensor.random(key, shape, 8000, cap=8192)
+        st = shuffle_and_pad(st, key, 4)
+        omega = st.with_values(jnp.ones_like(st.values))
+        fs = [jax.random.normal(jax.random.fold_in(key, d), (s, r)) / r ** 0.5
+              for d, s in enumerate(shape)]
+        spec = SparseTensor(P("data", None), P("data"), P("data"), st.shape,
+                            st.nnz, None)
+        return st, omega, fs, spec
 
     def shard(fn, in_specs, out_specs):
         return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs, check_vma=False))
 
-    for mode in range(3):
-        x = jax.random.normal(jax.random.fold_in(key, 9), (shape[mode], r))
-        def both(s, o, f, x):
-            ops = als.gram_slabs(s, o, list(f), mode)
-            return (als.slab_matvec(ops, x, 0.1, ctx),
-                    als.slab_rhs(ops, shape[mode], ctx))
-        got_y, got_b = shard(both, (spec, spec, (rep,) * 3, rep),
-                             (rep, rep))(st, omega, tuple(fs), x)
-        want_y = als.gram_matvec(omega, fs, mode, x, 0.1)
-        want_b = mttkrp(st, [None if e == mode else f
-                             for e, f in enumerate(fs)], mode)
-        for g, w in ((got_y, want_y), (got_b, want_b)):
-            scale = float(jnp.max(jnp.abs(w)))
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5 * scale)
+    # each shard holds 2,048 entries: 171 to 256 a row (K = 128) in the
+    # first tensor, 5.1 a row (K = 8) in the last mode of the second
+    widths = []
+    for shape in ((12, 10, 8), (12, 10, 400)):
+        st, omega, fs, spec = problem(shape)
+        for mode in range(3):
+            x = jax.random.normal(jax.random.fold_in(key, 9),
+                                  (shape[mode], r))
+            def both(s, o, f, x):
+                ops = als.gram_slabs(s, o, list(f), mode)
+                widths.append(ops.width)
+                return (als.slab_matvec(ops, x, 0.1, ctx),
+                        als.slab_rhs(ops, shape[mode], ctx))
+            got_y, got_b = shard(both, (spec, spec, (rep,) * 3, rep),
+                                 (rep, rep))(st, omega, tuple(fs), x)
+            want_y = als.gram_matvec(omega, fs, mode, x, 0.1)
+            want_b = mttkrp(st, [None if e == mode else f
+                                 for e, f in enumerate(fs)], mode)
+            for g, w in ((got_y, want_y), (got_b, want_b)):
+                scale = float(jnp.max(jnp.abs(w)))
+                np.testing.assert_allclose(g, w, rtol=1e-5,
+                                           atol=1e-5 * scale)
+    assert widths == [128] * 5 + [8], widths
     print("SLAB-OPERATOR-SHARDED-OK")
 
     obs.enable()
-    sweep = shard(lambda s, o, f: tuple(als_sweep(s, o, list(f), 1e-3,
-                                                  cg_iters=12, ctx=ctx)),
-                  (spec, spec, (rep,) * 3), (rep,) * 3)
-    got = sweep(st, omega, tuple(fs))
-    counters = obs.get_registry().summary()["counters"]
-    assert counters.get("als/gram/slab") == 3.0, counters
-    want = als_sweep(st, omega, fs, 1e-3, cg_iters=12,
-                     mttkrp_path="all_at_once")
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
+    for shape in ((12, 10, 8), (12, 10, 400)):
+        st, omega, fs, spec = problem(shape)
+        obs.get_registry().reset()
+        sweep = shard(lambda s, o, f: tuple(als_sweep(s, o, list(f), 1e-3,
+                                                      cg_iters=12, ctx=ctx)),
+                      (spec, spec, (rep,) * 3), (rep,) * 3)
+        got = sweep(st, omega, tuple(fs))
+        counters = obs.get_registry().summary()["counters"]
+        assert counters.get("als/gram/slab") == 3.0, counters
+        want = als_sweep(st, omega, fs, 1e-3, cg_iters=12,
+                         mttkrp_path="all_at_once")
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
     print("SLAB-SWEEP-SHARDED-OK")
 """)
 
 
 def test_slab_operator_under_data_shard_map():
     """Under a data-axis ``shard_map`` each shard builds its own operator
-    over its nonzeros; the psum over the data axis makes the matvec and
-    the right-hand side the COO ones of the whole tensor, and the sharded
-    sweep (every mode on slabs) lands on the local COO sweep."""
+    over its nonzeros, at 128 slots a slab and at 8; the psum over the
+    data axis makes the matvec and the right-hand side the COO ones of the
+    whole tensor, and the sharded sweep (every mode on slabs) lands on the
+    local COO sweep."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _SHARDED, _ROOT],
                          capture_output=True, text=True, env=env,

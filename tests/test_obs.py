@@ -323,8 +323,9 @@ _PLUMBING = ("parameter", "constant", "get-tuple-element", "tuple", "copy",
 
 
 def _instructions(hlo_text):
-    """``(computation, name, opcode, op_name, elements)`` of every
-    instruction, and the computations called as fusions or reducers."""
+    """``(computation, name, opcode, op_name, elements, fill)`` of every
+    instruction (``fill``: every operand a constant, so it reads nothing),
+    and the computations called as fusions or reducers."""
     out, called, comp = [], set(), None
     for line in hlo_text.splitlines():
         m = _COMPUTATION.match(line)
@@ -336,17 +337,20 @@ def _instructions(hlo_text):
             continue
         name, rest = m.groups()
         called.update(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", rest))
-        opcode = re.search(r"(?:^|\s)([a-z][\w\-]*)\(", rest)
+        opcode = re.search(r"(?:^|\s)([a-z][\w\-]*)\(([^)]*)\)", rest)
         dims = re.match(r"\w+\[([\d,]*)\]", rest)
         elements = 0 if dims is None else math.prod(
             int(d) for d in dims.group(1).split(",") if d)
         op_name = re.search(r'op_name="([^"]*)"', rest)
+        operands = opcode.group(2).split(", ") if opcode else []
+        fill = bool(operands) and all(
+            o.lstrip("%").startswith("constant") for o in operands)
         out.append((comp, name, opcode.group(1) if opcode else "",
-                    op_name.group(1) if op_name else None, elements))
+                    op_name.group(1) if op_name else None, elements, fill))
     return out, called
 
 
-def _compiled_sweep_text(nnz=2000):
+def _compiled_sweep_text(nnz=2000, **kw):
     from repro.core.completion import als_sweep
     from repro.data import synthetic
 
@@ -357,35 +361,57 @@ def _compiled_sweep_text(nnz=2000):
     fs = tuple(jax.random.normal(jax.random.fold_in(key, d), (n, 5))
                for d, n in enumerate(shape))
     fn = jax.jit(lambda s, o, f: tuple(als_sweep(s, o, list(f), 1e-4,
-                                                 cg_iters=20)))
+                                                 cg_iters=20, **kw)))
     return fn.lower(st, omega, fs).compile().as_text(), st.cap
 
 
-def test_compiled_sweep_operations_carry_their_scopes():
-    text, cap = _compiled_sweep_text()
+def _check_scopes(text, cap, holders):
+    """Every operation of the compiled sweep ``text`` names its mode, and
+    every gather and scatter one of ``holders``."""
     insts, called = _instructions(text)
     top = [i for i in insts if i[0] not in called
            and i[2] not in ("while", "conditional", "call")]
     scoped = [i for i in top if i[3] is not None and i[2] != "parameter"]
     assert scoped
-    for comp, name, opcode, op_name, _ in scoped:
+    for comp, name, opcode, op_name, _, _ in scoped:
         assert re.search(r"/mode_\d/", op_name), (name, op_name)
     # what carries no op_name is XLA's own plumbing, or a fusion of it,
-    # and none of it runs over the nonzeros
-    for comp, name, opcode, op_name, elements in top:
+    # and none of it runs over the nonzeros; a fill of constants reads
+    # nothing
+    for comp, name, opcode, op_name, elements, fill in top:
         if op_name is None:
             assert opcode in _PLUMBING + ("fusion",), (name, opcode)
-            if opcode in ("copy", "fusion"):
+            if opcode in ("copy", "fusion") and not fill:
                 assert elements < cap, (name, opcode, elements)
-    # every gather and scatter, fused or not, names its kernel family
+    # every gather and scatter, fused or not, names where it runs
     moves = [i for i in insts if i[2] in ("gather", "scatter")]
     assert {i[2] for i in moves} == {"gather", "scatter"}
-    for comp, name, opcode, op_name, _ in moves:
-        assert re.search(r"/(tttp|mttkrp)/", op_name or ""), (name, op_name)
+    for comp, name, opcode, op_name, _, _ in moves:
+        assert re.search(f"/({'|'.join(holders)})/", op_name or ""), (
+            name, op_name)
     # the solver's phases are named too
     names = " ".join(i[3] for i in scoped)
     for phase in ("rhs", "matvec", "cg_update"):
         assert f"/{phase}/" in names, phase
+
+
+def test_compiled_sweep_operations_carry_their_scopes():
+    """The default sweep of a tensor of 50, 67 and 100 nonzeros a row:
+    every mode on the row-slab operator (64 slots a slab, then 128). A
+    gather or scatter of a CG matvec names its kernel family; the build's
+    gathers name ``gram_build``, the right-hand side's sorted scatter
+    ``rhs``. The zeros of a build step past the last row are a fill."""
+    text, cap = _compiled_sweep_text()
+    assert "/mode_0/gram_build/" in text
+    _check_scopes(text, cap, ("tttp", "mttkrp", "gram_build", "rhs"))
+
+
+def test_compiled_coo_sweep_operations_carry_their_scopes():
+    """The same sweep on the COO Gram matvec (kept by an explicit
+    planner path): every gather and scatter names its kernel family."""
+    text, cap = _compiled_sweep_text(mttkrp_path="all_at_once")
+    assert "gram_build" not in text
+    _check_scopes(text, cap, ("tttp", "mttkrp"))
 
 
 @pytest.mark.parametrize("family", ["mttkrp", "cg_matvec"])

@@ -2,7 +2,8 @@
 
 (a) The jitted ALS and GGN sweeps compile for one chip at the benchmark's
     ``netflix`` deployment (``chipbench/configs/netflix.json``: Netflix
-    extents, rank 32, one chip's 1/32 of the ratings) and fit
+    extents, rank 32, one chip's 1/32 of the ratings; ALS with every mode
+    on the row-slab operator, the users' at 8 slots a slab) and fit
     the chip's 16 GiB with 10% headroom, by ``memory_analysis()``. At the
     benchmark's function-10b extents every ALS mode takes the row-slab Gram
     operator and needs no more device bytes than the COO matvec.
@@ -76,8 +77,8 @@ def _device_bytes(compiled) -> int:
 
 
 def _als_compile(sharding, shape, m, r, lam, **kw):
-    """The jitted ALS sweep compiled for ``sharding``'s chip, and the
-    ``als/gram/*`` counters its trace bumped."""
+    """The jitted ALS sweep compiled for ``sharding``'s chip, the
+    ``als/gram/*`` counters its trace bumped and the gauges it set."""
     st = SparseTensor(_sds(sharding, (m, len(shape)), jnp.int32),
                       _sds(sharding, (m,), jnp.float32),
                       _sds(sharding, (m,), jnp.bool_), tuple(shape), m)
@@ -88,12 +89,12 @@ def _als_compile(sharding, shape, m, r, lam, **kw):
     obs.enable()
     try:
         compiled = fn.lower(st, st, fs).compile()
-        counters = obs.get_registry().summary()["counters"]
+        summary = obs.get_registry().summary()
     finally:
         obs.disable()
         obs.get_registry().reset()
-    return compiled, {k: v for k, v in counters.items()
-                      if k.startswith("als/gram/")}
+    return compiled, {k: v for k, v in summary["counters"].items()
+                      if k.startswith("als/gram/")}, summary["gauges"]
 
 
 @pytest.mark.parametrize("solver", ["als", "ggn"])
@@ -102,9 +103,12 @@ def test_sweep_fits_one_chip(one_chip, solver):
     m, shape, r, lam = (cfg["nnz_per_chip"], tuple(cfg["shape"]), cfg["rank"],
                         cfg["lam"])
     if solver == "als":
-        compiled, counters = _als_compile(one_chip, shape, m, r, lam)
-        # the user mode's rows are short (about 6.5 nonzeros): COO there
-        assert counters == {"als/gram/coo": 1.0, "als/gram/slab": 2.0}
+        compiled, counters, gauges = _als_compile(one_chip, shape, m, r, lam)
+        # the user mode's rows are short (about 6.5 nonzeros): slabs of 8
+        # slots there, of 128 in the movie and day modes
+        assert counters == {"als/gram/slab": 3.0}
+        assert [gauges[f"als/gram/mode_{d}/width"] for d in range(3)] == [
+            8, 128, 128]
     else:
         st = SparseTensor(_sds(one_chip, (m, 3), jnp.int32),
                           _sds(one_chip, (m,), jnp.float32),
@@ -127,9 +131,10 @@ def test_als_slab_path_at_function_extents(one_chip):
     explicit ``mttkrp_path`` keeps."""
     cfg = _config("function-10b")
     args = (cfg["shape"], cfg["nnz_per_chip"], cfg["rank"], cfg["lam"])
-    slab, counters = _als_compile(one_chip, *args)
+    slab, counters, _ = _als_compile(one_chip, *args)
     assert counters == {"als/gram/slab": 3.0}
-    coo, counters = _als_compile(one_chip, *args, mttkrp_path="all_at_once")
+    coo, counters, _ = _als_compile(one_chip, *args,
+                                    mttkrp_path="all_at_once")
     assert counters == {"als/gram/coo": 3.0}
     assert _device_bytes(slab) <= _device_bytes(coo), (
         _device_bytes(slab) / 2 ** 30, _device_bytes(coo) / 2 ** 30)
